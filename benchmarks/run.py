@@ -89,8 +89,9 @@ def main(argv=None) -> None:
 
     # The preset layer is the one sanctioned XLA_FLAGS surface; applying
     # here (before any bench imports jax) mirrors initialize_runtime.
-    from repro.launch import xla_presets
+    from repro.launch import compile_cache, xla_presets
     xla_presets.apply()
+    compile_cache.enable()
 
     modules = [m for m in MODULES
                if args.only is None or args.only in m]

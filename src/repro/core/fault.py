@@ -52,11 +52,15 @@ TRANSIENT_RECOVERED = "transient_recovered"
 PERSISTENT = "persistent"
 INTERMITTENT_PROMOTED = "intermittent_promoted"
 
-# Errors a detector may legitimately *interpret as a fault* when a stage's
-# HW path raises them (numeric/shape breakage of the kind a defective
-# datapath produces).  Anything else propagates — a fail-open
-# ``except Exception`` here once swallowed genuine bugs silently.
-EXPECTED_STAGE_ERRORS = (ValueError, TypeError, ArithmeticError)
+# Errors a detector may *interpret as a fault* when a stage's HW path
+# raises them: only a non-finite value (``jax_debug_nans`` raises
+# FloatingPointError).  Everything else propagates as a program error —
+# above all a lowering or compile refusal (Mosaic raises ValueError): a
+# kernel the compiler refuses on a healthy chip is a bug to fix, and
+# quarantining it onto its oracle would hide that the device path never
+# ran.  A fail-open ``except Exception`` here once swallowed genuine bugs
+# silently.
+EXPECTED_STAGE_ERRORS = (FloatingPointError,)
 
 
 @dataclass(frozen=True)
@@ -499,8 +503,8 @@ class CanaryChecker:
         try:
             hw_out, sw_out = self._run_both(stage)
         except EXPECTED_STAGE_ERRORS as e:
-            # Numeric/shape breakage on the HW path is itself the fault
-            # signal; anything unexpected re-raises (no fail-open except).
+            # A non-finite value on the HW path is itself the fault
+            # signal; anything else re-raises (no fail-open except).
             log.warning("canary: stage raised; treating as a fault",
                         stage=stage.name, error=type(e).__name__,
                         detail=e)

@@ -50,7 +50,8 @@ class Stage:
         return fn(*args, **kw)
 
     def canary_inputs(self, seed: int = 0):
-        """Deterministic inputs drawn from the port specs."""
+        """Deterministic inputs drawn from the port specs, mapped into the
+        op's operating domain when its spec declares one."""
         key = jax.random.PRNGKey(seed)
         outs = []
         for i, sds in enumerate(self.ports):
@@ -60,4 +61,6 @@ class Stage:
             else:
                 outs.append(jax.random.randint(k, sds.shape, 0, 128
                                                ).astype(sds.dtype))
+        if self.spec is not None and self.spec.canary_domain is not None:
+            return tuple(self.spec.canary_domain(*outs))
         return tuple(outs)

@@ -1,27 +1,30 @@
 """Pallas TPU kernel for the Fig. 4 checksum module.
 
-Grid over row blocks of the word view; each block reduces to a single
-partial popcount (VPU bit ops, no MXU); the host-side wrapper sums the
-per-block partials.  This is the cheap always-on detector the paper routes
+Grid over row blocks of the word view; each block reduces its rows to one
+lane-dense (1, 128) row of partial popcounts (VPU bit ops, no MXU), and the
+wrapper sums the partials.  A lane row keeps the output block aligned to
+the TPU tiling; every per-lane partial is at most ``32 * block_rows``, so
+it is exact in f32.  This is the cheap always-on detector the paper routes
 through the Cohort queues; here it runs over stage outputs / canaries.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _checksum_kernel(x_ref, o_ref, *, block_rows: int):
+def _checksum_kernel(x_ref, o_ref):
     x = x_ref[...].astype(jnp.uint32)
     x = (x & 0x55555555) + ((x >> 1) & 0x55555555)
     x = (x & 0x33333333) + ((x >> 2) & 0x33333333)
     x = (x & 0x0F0F0F0F) + ((x >> 4) & 0x0F0F0F0F)
     x = (x & 0x00FF00FF) + ((x >> 8) & 0x00FF00FF)
     x = (x & 0x0000FFFF) + ((x >> 16) & 0x0000FFFF)
-    o_ref[0, 0] = jnp.sum(x.astype(jnp.uint32))
+    # per-word counts are <= 32: exact through int32 (Mosaic has no
+    # uint32 -> f32 cast) and f32
+    counts = x.astype(jnp.int32).astype(jnp.float32)
+    o_ref[...] = jnp.sum(counts, axis=0, keepdims=True)
 
 
 def checksum_pallas_words(words, *, block_rows: int = 64, lanes: int = 128,
@@ -33,11 +36,11 @@ def checksum_pallas_words(words, *, block_rows: int = 64, lanes: int = 128,
     padded = jnp.zeros((nb * per_block,), jnp.uint32).at[:n].set(words)
     x = padded.reshape(nb * block_rows, lanes)
     partials = pl.pallas_call(
-        functools.partial(_checksum_kernel, block_rows=block_rows),
+        _checksum_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, 1), jnp.uint32),
+        out_specs=pl.BlockSpec((None, 1, lanes), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, lanes), jnp.float32),
         interpret=interpret,
     )(x)
     return jnp.sum(partials.astype(jnp.uint32))

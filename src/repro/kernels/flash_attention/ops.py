@@ -16,6 +16,7 @@ from repro import viscosity
 from repro.kernels import tuning
 from repro.kernels.flash_attention import ref as _ref
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
+from repro.obs import metrics
 from repro.viscosity import lanefault
 
 
@@ -33,14 +34,19 @@ def _kernel_path(q, k, v, *, causal=True, window=0, softcap=0.0, scale=0.0,
                  interpret=False):
     fault = lanefault.injection("flash_attention")
     if q_offset is not None or kv_len is not None:
-        # decode-style calls carry dynamic positions; the kernel targets
-        # train/prefill. Fall back to the software lowering (still correct).
-        # This branch IS the HW lowering for decode, so an active lane
-        # fault corrupts it too (wrapper-level: same masked-where).
+        # Decode-style calls carry dynamic positions, and there is no
+        # decode kernel yet (ROADMAP 1.2): until there is, this chunked jnp
+        # lowering IS the HW route's decode path, and it is counted as
+        # such.  An active lane fault corrupts it too (wrapper-level: same
+        # masked-where).
+        metrics.inc("attention_lowerings_traced_total", phase="decode",
+                    lowering="jnp_chunked")
         out = _ref.attention_chunked(q, k, v, causal=causal, window=window,
                                      softcap=softcap, scale=scale,
                                      q_offset=q_offset, kv_len=kv_len)
         return fault.corrupt_tree(out) if fault is not None else out
+    metrics.inc("attention_lowerings_traced_total", phase="prefill",
+                lowering="pallas_interpret" if interpret else "pallas")
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     # Tuned score-tile (bq, bk) for this (shape, dtype, active routing
@@ -77,6 +83,9 @@ def _lane_slicer(args, kw, keep):
 
 def _sw_path(q, k, v, *, kv_chunk=None, bq=128, bk=128, interpret=False,
              **kw):
+    metrics.inc("attention_lowerings_traced_total",
+                phase="decode" if kw.get("q_offset") is not None
+                else "prefill", lowering="jnp_chunked")
     if not kv_chunk:
         B, Sq, H, D = q.shape
         cfg = tuning.lookup("flash_attention", "sw",

@@ -10,8 +10,12 @@ MXU matmuls on (L, N)/(L, P) tiles:
     y_st   = (C @ state) * exp(cum)       (L, N)@(N, P)  MXU
     state' = exp(tot) * state + (B*scale)^T @ xdt  (N, L)@(L, P)  MXU
 
-Inputs are pre-scaled outside the kernel: ``xdt = x * dt`` and
-``da = dt * A`` so the kernel touches only dense, layout-friendly operands.
+Inputs are pre-scaled outside the kernel: ``xdt = x * dt`` and the
+within-chunk decay prefix sums ``cum = cumsum(dt * A)`` (as a column and
+as a row, plus the chunk total), so the kernel touches only dense operands
+and no scan.  Operands are laid out head-major and chunked,
+``(B, H, n_chunks, L, ·)``, so the last two dims of every block are whole
+array dims — the Mosaic tiling rule holds for any chunk length.
 """
 from __future__ import annotations
 
@@ -25,38 +29,38 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.viscosity.lanefault import apply_fault
 
 
-def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, state_scr, *,
-                L: int, N: int, P: int, lane_fault=None):
+def _ssd_kernel(xdt_ref, cum_col_ref, cum_row_ref, tot_ref, b_ref, c_ref,
+                y_ref, state_scr, *, L: int, lane_fault=None):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    xdt = xdt_ref[0, :, 0, :].astype(jnp.float32)      # (L, P)
-    da = da_ref[0, :, 0].astype(jnp.float32)           # (L,)
-    b = b_ref[0].astype(jnp.float32)                   # (L, N)
-    c = c_ref[0].astype(jnp.float32)                   # (L, N)
+    xdt = xdt_ref[...]                                 # (L, P) f32
+    cum_col = cum_col_ref[...]                         # (L, 1)
+    cum_row = cum_row_ref[...]                         # (1, L)
+    tot = tot_ref[...]                                 # (1, 1)
+    b = b_ref[...].astype(jnp.float32)                 # (L, N)
+    c = c_ref[...].astype(jnp.float32)                 # (L, N)
 
-    cum = jnp.cumsum(da)                               # (L,)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (L, L)
     rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    dec = jnp.exp(cum[:, None] - cum[None, :])
+    dec = jnp.exp(cum_col - cum_row)
     w = jnp.where(rows >= cols, cb * dec, 0.0)
     y_intra = jax.lax.dot_general(w, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     state = state_scr[...]                             # (N, P)
     y_state = jax.lax.dot_general(c, state, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y = y_intra + y_state * jnp.exp(cum)[:, None]
+    y = y_intra + y_state * jnp.exp(cum_col)
     # Value-level fault injection (lanefault): masked corruption of the
     # chunk's head-channel lane axis; absent from the trace when healthy.
-    y_ref[0, :, 0, :] = apply_fault(y, lane_fault).astype(y_ref.dtype)
+    y_ref[...] = apply_fault(y, lane_fault).astype(y_ref.dtype)
 
-    tot = cum[L - 1]
-    bscale = b * jnp.exp(tot - cum)[:, None]           # (L, N)
+    bscale = b * jnp.exp(tot - cum_col)                # (L, N)
     upd = jax.lax.dot_general(bscale, xdt, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     state_scr[...] = state * jnp.exp(tot) + upd
@@ -75,25 +79,32 @@ def ssd_chunked_pallas(x, dt, A, B_, C, *, chunk: int = 128,
     L = min(chunk, S)
     assert S % L == 0, (S, L)
     nc = S // L
+    f32 = jnp.float32
 
-    xdt = (x.astype(jnp.float32) * dt.astype(jnp.float32)[..., None])
-    da = dt.astype(jnp.float32) * A.astype(jnp.float32)[None, None, :]
+    xdt = x.astype(f32) * dt.astype(f32)[..., None]
+    xdt = xdt.reshape(Bt, nc, L, H, P).transpose(0, 3, 1, 2, 4)
+    da = (dt.astype(f32) * A.astype(f32)[None, None, :])
+    cum = jnp.cumsum(da.reshape(Bt, nc, L, H).transpose(0, 3, 1, 2), -1)
+    tot = cum[..., -1:]                                # (B, H, nc, 1)
 
-    kernel = functools.partial(_ssd_kernel, L=L, N=N, P=P,
-                               lane_fault=lane_fault)
-    grid = (Bt, H, nc)
+    def per_chunk(*tail):     # block of one (b, h, chunk), whole last dims
+        return pl.BlockSpec((None, None, None) + tail,
+                            lambda b, h, ci: (b, h, ci, 0, 0))
+
+    def per_seq(*tail):       # B_/C: shared across heads
+        return pl.BlockSpec((None, None) + tail,
+                            lambda b, h, ci: (b, ci, 0, 0))
+
+    kernel = functools.partial(_ssd_kernel, L=L, lane_fault=lane_fault)
     y = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda b, h, ci: (b, ci, h, 0)),
-            pl.BlockSpec((1, L, 1), lambda b, h, ci: (b, ci, h)),
-            pl.BlockSpec((1, L, N), lambda b, h, ci: (b, ci, 0)),
-            pl.BlockSpec((1, L, N), lambda b, h, ci: (b, ci, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, L, 1, P), lambda b, h, ci: (b, ci, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bt, S, H, P), x.dtype),
-        scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        grid=(Bt, H, nc),
+        in_specs=[per_chunk(L, P), per_chunk(L, 1), per_chunk(1, L),
+                  per_chunk(1, 1), per_seq(L, N), per_seq(L, N)],
+        out_specs=per_chunk(L, P),
+        out_shape=jax.ShapeDtypeStruct((Bt, H, nc, L, P), x.dtype),
+        scratch_shapes=[pltpu.VMEM((N, P), f32)],
         interpret=interpret,
-    )(xdt, da, B_, C)
-    return y
+    )(xdt, cum[..., None], cum[..., None, :], tot[..., None],
+      B_.reshape(Bt, nc, L, N), C.reshape(Bt, nc, L, N))
+    return y.transpose(0, 2, 3, 1, 4).reshape(Bt, S, H, P)

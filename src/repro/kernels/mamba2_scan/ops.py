@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from repro import viscosity
@@ -48,6 +49,14 @@ def _lane_slicer(args, kw, keep):
     return (x[..., jnp.asarray(keep, jnp.int32)], dt, A, B_, C), kw
 
 
+def _canary_domain(x, dt, A, B_, C):
+    # The model feeds dt = softplus(.) > 0 and A = -exp(A_log) < 0, so
+    # every decay exp(dt * A) is <= 1 and the state stays bounded.  Raw
+    # N(0, 1) draws give dt * A > 0 half the time, and the scan then grows
+    # without bound (kernel and oracle agree only in relative terms).
+    return x, jax.nn.softplus(dt), -jnp.exp(A), B_, C
+
+
 SSD = viscosity.defop(
     "mamba2_ssd",
     ref=_sw,
@@ -58,6 +67,7 @@ SSD = viscosity.defop(
     flops=lambda x, dt, A, B_, C, **kw: _ref.ssd_flops(
         x.shape[0], x.shape[1], x.shape[2], x.shape[3], B_.shape[-1]),
     lane_slicer=_lane_slicer,
+    canary_domain=_canary_domain,
 )
 
 

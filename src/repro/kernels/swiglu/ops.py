@@ -12,6 +12,10 @@ from repro.kernels.swiglu.kernel import swiglu_pallas
 from repro.viscosity import lanefault
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _hw(x, w1, w3, w2, *, act: str = "silu", interpret: bool = False,
         bm=None, bf=None, bs=None):
     M, D = x.shape
@@ -24,17 +28,23 @@ def _hw(x, w1, w3, w2, *, act: str = "silu", interpret: bool = False,
         cfg = tuning.lookup("swiglu_mlp", "hw", (M, D, F), x.dtype) or {}
     else:
         cfg = {}
-    if bm is None:
-        bm = cfg.get("bm") or (128 if M % 128 == 0 else
-                               (8 if M % 8 == 0 else 1))
+    # Row tile: one 128-row MXU tile, or M rounded up to the f32 sublane
+    # (8) when M is smaller.  M is padded to a whole number of row tiles
+    # and the result sliced back — rows are independent, so the pad rows
+    # never touch the real ones (a 13-token prefill, a 1-token decode).
+    bm = min(bm or cfg.get("bm") or 128, _round_up(M, 8))
     if bf is None:
         bf = cfg.get("bf") or (512 if F % 512 == 0 else
                                (128 if F % 128 == 0 else F))
     if bs is None:
         bs = cfg.get("bs") or (128 if min(bf, F) % 128 == 0 else bf)
-    return swiglu_pallas(x, w1, w3, w2, act=act, bm=bm, bf=bf, bs=bs,
-                         interpret=interpret,
-                         lane_fault=lanefault.injection("swiglu_mlp"))
+    Mp = _round_up(M, bm)
+    if Mp != M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
+    out = swiglu_pallas(x, w1, w3, w2, act=act, bm=bm, bf=bf, bs=bs,
+                        interpret=interpret,
+                        lane_fault=lanefault.injection("swiglu_mlp"))
+    return out[:M]
 
 
 def _lane_slicer(args, kw, keep):

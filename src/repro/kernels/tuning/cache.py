@@ -31,14 +31,10 @@ DEFAULT_PLAN = "default"
 
 def backend_fingerprint() -> str:
     """jax version + platform + device kind: the cache partition key."""
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", dev.platform)
-        return f"jax-{jax.__version__}/{dev.platform}/{kind}"
-    except Exception:
-        return "jax-unknown/none/none"
+    dev = jax.devices()[0]
+    return f"jax-{jax.__version__}/{dev.platform}/{dev.device_kind}"
 
 
 def plan_digest(plan_key) -> str:

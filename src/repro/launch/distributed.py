@@ -88,9 +88,9 @@ def initialize_runtime(
     ``num_processes <= 1`` with no coordinator address is the
     single-process no-op, so the same entry point serves tests and
     real launches.  ``cpu_collectives`` selects the CPU cross-process
-    collective backend (gloo) where this jax exposes the knob — without
-    it, CPU cross-process *computations* fail but the coordination
-    service (and so ``KVCoordinator``) still works.  ``xla_preset``
+    collective backend (gloo) — without it, CPU cross-process
+    *computations* fail but the coordination service (and so
+    ``KVCoordinator``) still works.  ``xla_preset``
     merges the per-backend XLA flag preset (``launch/xla_presets.py``)
     before jax initializes — "auto" infers the backend from the
     environment pin, an explicit name selects that preset, and None
@@ -108,10 +108,7 @@ def initialize_runtime(
     if num_processes <= 1 and coordinator_address is None:
         return DistributedRuntime(num_processes=1, process_id=0)
     if cpu_collectives is not None:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
-        except Exception:  # noqa: BLE001 - probing a version-dependent jax
-            pass  # config knob; absence is expected, not an error path
+        jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -480,37 +477,15 @@ class HostTimeoutError(RuntimeError):
         self.host_id = int(host_id)
 
 
-_CLIENT_ERRORS: Optional[Tuple[type, ...]] = None
-
-
 def coordination_client_errors() -> Tuple[type, ...]:
     """Error types the coordination-service client raises (timeouts,
-    disconnects, missing keys).  Probed lazily because the taxonomy
-    varies across jaxlibs; ``RuntimeError`` is the floor every known
-    client satisfies.  This is the *only* exception set coordination
-    code may catch broadly — anything outside it is a genuine bug and
-    must propagate."""
-    global _CLIENT_ERRORS
-    if _CLIENT_ERRORS is None:
-        errs: List[type] = [RuntimeError]
-        try:
-            from jax._src.lib import xla_client as _xc
+    disconnects, missing keys): ``jax.errors.JaxRuntimeError`` and the
+    ``RuntimeError`` floor.  This is the *only* exception set
+    coordination code may catch broadly — anything outside it is a
+    genuine bug and must propagate."""
+    import jax
 
-            err = getattr(_xc, "XlaRuntimeError", None)
-            if isinstance(err, type) and issubclass(err, Exception):
-                errs.append(err)
-        except Exception:  # noqa: BLE001 - probing a version-dependent
-            pass  # jax internal; absence is expected
-        try:
-            import jax
-
-            err = getattr(getattr(jax, "errors", None), "JaxRuntimeError", None)
-            if isinstance(err, type) and issubclass(err, Exception):
-                errs.append(err)
-        except Exception:  # noqa: BLE001 - same version probe
-            pass
-        _CLIENT_ERRORS = tuple(dict.fromkeys(errs))
-    return _CLIENT_ERRORS
+    return (RuntimeError, jax.errors.JaxRuntimeError)
 
 
 class LocalCoordinator:

@@ -35,8 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import optim
 from repro.configs import ARCH_NAMES, SHAPES, applicable, get_config
 from repro.launch import hlo_analysis
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import chip_peaks, make_production_mesh
 from repro.launch.partition import (batch_pspec, make_cache_pspec_fn,
                                     params_pspecs, rules_for, tree_pspecs)
 from repro.launch.sharding import axis_rules
@@ -44,6 +43,11 @@ from repro.models import build_model, input_specs, params_specs
 from repro.obs.logging import configure as obs_configure, get_logger
 
 log = get_logger("launch.dryrun")
+
+# The chip the dry-run's roofline terms are computed for: the mesh is
+# virtual CPU devices, so the target kind is named here, not read from them.
+TARGET_KIND = "TPU v5 lite"
+PEAKS = chip_peaks(TARGET_KIND)
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "artifacts", "dryrun")
@@ -272,13 +276,13 @@ class SkipCell(Exception):
 def roofline_terms(stats: hlo_analysis.CompStats, n_chips: int,
                    mfl: float) -> Dict[str, Any]:
     coll = float(sum(stats.coll_bytes.values()))
-    t_comp = stats.flops / PEAK_FLOPS_BF16        # per-device flops already
-    t_mem = stats.bytes_hbm / HBM_BW
-    t_coll = coll / ICI_BW
+    t_comp = stats.flops / PEAKS.flops_bf16        # per-device flops already
+    t_mem = stats.bytes_hbm / PEAKS.hbm_bw
+    t_coll = coll / PEAKS.ici_link_bw
     terms = {"compute_s": t_comp, "memory_s": t_mem, "collective_s": t_coll}
     dom = max(terms, key=terms.get)
     bound = max(t_comp, t_mem, t_coll)
-    mfu = (mfl / n_chips / PEAK_FLOPS_BF16) / bound if bound > 0 else 0.0
+    mfu = (mfl / n_chips / PEAKS.flops_bf16) / bound if bound > 0 else 0.0
     return {**terms, "dominant": dom,
             "useful_flops_ratio": (mfl / n_chips) / max(stats.flops, 1.0),
             "roofline_fraction": mfu,
@@ -377,7 +381,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "roofline": roofline_terms(stats, n_chips, meta["model_flops"]),
         })
         rec["roofline"]["score_bytes_per_dev"] = score_b
-        hw_mem = max(stats.bytes_hbm - score_b, 0.0) / HBM_BW
+        hw_mem = max(stats.bytes_hbm - score_b, 0.0) / PEAKS.hbm_bw
         terms = {"compute_s": rec["roofline"]["compute_s"],
                  "memory_s": hw_mem,
                  "collective_s": rec["roofline"]["collective_s"]}
@@ -386,7 +390,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             **terms,
             "dominant": max(terms, key=terms.get),
             "roofline_fraction":
-                (meta["model_flops"] / n_chips / PEAK_FLOPS_BF16) / bound
+                (meta["model_flops"] / n_chips / PEAKS.flops_bf16) / bound
                 if bound > 0 else 0.0}
     except SkipCell as e:
         rec.update({"status": "skip", "reason": str(e)})

@@ -27,10 +27,8 @@ def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
             f"{n - len(devices)} device(s) — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             " before importing jax (see launch/dryrun.py)")
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):   # added after jax 0.4.x; Auto is
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices[:n], **kw)
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -110,7 +108,28 @@ class FleetMeshView:
         return _mesh((n,), tuple(axes), devices=devs)
 
 
-# Hardware constants for the roofline (assignment-provided, TPU v5e-class).
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
+# ------------------------------------------------------ per-chip peaks
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float      # FLOP/s per chip
+    hbm_bw: float          # bytes/s per chip
+    ici_link_bw: float     # bytes/s per ICI link
+
+
+# Published peaks keyed by ``jax.Device.device_kind`` (a TPU v5e reports
+# "TPU v5 lite").  Source: Google Cloud TPU documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip
+# (four links of 50 GB/s).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                             ici_link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of one chip kind; an unknown kind is an error, never a
+    default (a roofline against another chip's peaks is a wrong number)."""
+    if device_kind not in CHIP_PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}")
+    return CHIP_PEAKS[device_kind]

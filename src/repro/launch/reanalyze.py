@@ -15,8 +15,7 @@ import sys
 
 from repro.configs import get_config
 from repro.launch import hlo_analysis
-from repro.launch.dryrun import ART_DIR, roofline_terms
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.dryrun import ART_DIR, PEAKS, roofline_terms
 from repro.obs.logging import configure as obs_configure, get_logger
 
 log = get_logger("launch.reanalyze")
@@ -43,14 +42,14 @@ def reanalyze_one(json_path: str, hlo_path: str) -> bool:
     }
     rec["roofline"] = roofline_terms(stats, n_chips, rec["model_flops"])
     rec["roofline"]["score_bytes_per_dev"] = score_b
-    hw_mem = max(stats.bytes_hbm - score_b, 0.0) / HBM_BW
+    hw_mem = max(stats.bytes_hbm - score_b, 0.0) / PEAKS.hbm_bw
     terms = {"compute_s": rec["roofline"]["compute_s"], "memory_s": hw_mem,
              "collective_s": rec["roofline"]["collective_s"]}
     bound = max(terms.values())
     rec["roofline"]["hw_route"] = {
         **terms, "dominant": max(terms, key=terms.get),
         "roofline_fraction":
-            (rec["model_flops"] / n_chips / PEAK_FLOPS_BF16) / bound
+            (rec["model_flops"] / n_chips / PEAKS.flops_bf16) / bound
             if bound > 0 else 0.0}
     with open(json_path, "w") as f:
         json.dump(rec, f, indent=1)

@@ -6,10 +6,16 @@ fixed slot pool, with optional mid-stream fault injection under either
 failover mode (dispatcher-keyed recompile or resident health-mask).  With
 ``--verify`` every completion is checked bit-for-bit against a
 single-request reference decode.
+
+``--full`` serves the published config (e.g. qwen1.5-4b: 40 layers,
+d_model 2560) instead of the reduced CPU smoke config; weights are held in
+the compute dtype (bf16), which is what lets a 4B model fit one 16 GB
+accelerator.  Weights are random, drawn from ``--seed``.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -17,6 +23,8 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.configs.base import ModelConfig
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.obs.logging import configure as obs_configure, get_logger
 from repro.serve import (RECOMPILE, RESIDENT, ServeConfig, ServeEngine,
@@ -24,6 +32,24 @@ from repro.serve import (RECOMPILE, RESIDENT, ServeConfig, ServeEngine,
 from repro.viscosity import HW, INTERPRET, SW
 
 log = get_logger("launch.serve")
+
+
+def served_config(arch: str, *, full: bool) -> ModelConfig:
+    """The config the server runs: the published one with ``full``, else
+    its reduced CPU smoke twin — weights in the compute dtype either way
+    (f32 masters of a 4B model would not fit one 16 GB chip)."""
+    cfg = get_config(arch)
+    if not full:
+        cfg = cfg.reduced()
+    if cfg.is_encdec or cfg.stub_frontend:
+        raise SystemExit("serving targets decoder-only LM archs")
+    return dataclasses.replace(cfg, param_dtype=cfg.dtype)
+
+
+def init_params(cfg: ModelConfig, seed: int):
+    """Random weights from ``seed``, initialized in one jitted program (no
+    f32 temporaries of whole weight stacks)."""
+    return jax.jit(build_model(cfg).init)(jax.random.PRNGKey(seed))
 
 
 def main():
@@ -49,14 +75,16 @@ def main():
     ap.add_argument("--verify", action="store_true",
                     help="check every request against single-request "
                          "reference decode")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--full", action="store_true",
+                    help="published config at full width (needs an "
+                         "accelerator); default: the reduced config")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the weights and the workload")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
-    if cfg.is_encdec or cfg.stub_frontend:
-        raise SystemExit("serve demo targets decoder-only LM archs")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    compile_cache.enable()
+    cfg = served_config(args.arch, full=args.full)
+    params = init_params(cfg, args.seed)
     reqs = synthetic_workload(cfg.vocab_size, args.requests,
                               np.random.default_rng(args.seed),
                               max_prompt=args.prompt_len, min_new=4,

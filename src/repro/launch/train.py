@@ -1,9 +1,8 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
 Runs the fault-aware TrainRunner on a reduced (CPU-runnable) or full
-config.  On real hardware the same entry point runs the full config on the
-production mesh (--mesh data,model); this container is CPU-only, so the
-default is the reduced config on a single device.
+config.  The default is the reduced config on a single device; ``--full``
+selects the published config, which needs accelerator hardware.
 """
 from __future__ import annotations
 
@@ -14,6 +13,7 @@ import sys
 from repro import optim
 from repro.configs import ARCH_NAMES, get_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch import compile_cache
 from repro.obs.logging import configure as obs_configure, get_logger
 from repro.train import TrainConfig, TrainRunner
 from repro.viscosity import HW, INTERPRET, SW
@@ -41,6 +41,7 @@ def main():
                     choices=[HW, SW, INTERPRET])
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()

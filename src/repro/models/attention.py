@@ -19,6 +19,7 @@ from repro.kernels.flash_attention import ref as attn_ref
 from repro.launch.sharding import constrain
 from repro.models import rope as rope_mod
 from repro.models.layers import _he, rms_norm_simple
+from repro.obs import metrics
 
 
 def init_attention(key, d_model, n_heads, n_kv, head_dim, dtype, *,
@@ -202,6 +203,11 @@ def attn_decode(p, x, cache, t, *, n_heads, n_kv, head_dim, window=0,
         c["v"] = jax.lax.dynamic_update_slice(cache["v"], vw, (0, slot, 0, 0))
         c["pos"] = jax.lax.dynamic_update_slice(cache["pos"], tvec, (0, slot))
         k_all, v_all, pos_all = c["k"], c["v"], c["pos"]
+    # The LM decode step attends through the jnp oracle on every route:
+    # per-slot cache positions (ring buffers) have no kernel yet (ROADMAP
+    # 1.2).  Counted, so a run can report which lowering decode used.
+    metrics.inc("attention_lowerings_traced_total", phase="decode",
+                lowering="jnp_naive")
     o = attn_ref.attention_naive(
         q, k_all, v_all, causal=True, window=window, softcap=softcap,
         scale=scale, q_offset=jnp.full((B,), t, jnp.int32),

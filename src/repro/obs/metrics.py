@@ -92,6 +92,11 @@ SCHEMA: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         COUNTER, "tokens of all completions", ("section",)),
     "serve_virtual_time_seconds": (
         GAUGE, "virtual-clock span of the run", ("section",)),
+    # kernels: which lowering each attention call site was traced with
+    # (counted at trace time, once per compiled program, not per call)
+    "attention_lowerings_traced_total": (
+        COUNTER, "attention call sites traced, by phase and lowering",
+        ("phase", "lowering")),
     # fault / routing
     "fault_events_total": (
         COUNTER, "fault-log entries by kind", ("kind", "stage")),

@@ -3,7 +3,7 @@ from repro.serve.engine import (RECOMPILE, RESIDENT, Completion,
                                 FleetServeEngine, FleetSession, Request,
                                 ServeConfig, ServeEngine, ServeSession,
                                 percentile, reference_decode,
-                                validate_requests)
+                                reference_margins, validate_requests)
 from repro.serve.frontend import (BLOCK, EDF, FIFO, REJECT, SHED_LATEST,
                                   Frontend, FrontendConfig, summarize)
 from repro.serve.traffic import (ClosedLoop, Diurnal, FlashCrowd,
@@ -12,7 +12,7 @@ from repro.serve.traffic import (ClosedLoop, Diurnal, FlashCrowd,
                                  with_deadlines)
 
 __all__ = ["ServeConfig", "ServeEngine", "Request", "Completion",
-           "RECOMPILE", "RESIDENT", "reference_decode",
+           "RECOMPILE", "RESIDENT", "reference_decode", "reference_margins",
            "synthetic_workload", "percentile", "FleetConfig",
            "FleetServeEngine", "validate_requests",
            # streaming session API

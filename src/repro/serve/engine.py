@@ -53,6 +53,7 @@ real tokens at the end.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import time
 import warnings
@@ -272,24 +273,64 @@ class _SlotPool:
         return False
 
 
+def build_prefill(cfg: ModelConfig, plan: RoutingPlan, failover: str):
+    """The jitted prefill program for ``plan``: ``(params, batch)`` ->
+    (last-token logits, cache); RESIDENT adds the health-mask input."""
+    if failover == RESIDENT:
+        # Admissions after a fault must not stall in-flight decodes on
+        # a recompile either: prefill is resident too (one executable
+        # per prompt length, rerouted by the same health mask).
+        names = list(model_stage_names(cfg))
+
+        def prefill(params, batch, mask):
+            routes = plan.resident_routes(mask, names)
+            return build_model(cfg, routes=routes).prefill(params, batch)
+
+        return jax.jit(prefill)
+    return jax.jit(build_model(cfg, routes=plan).prefill)
+
+
+def build_decode(cfg: ModelConfig, plan: RoutingPlan, failover: str):
+    """The jitted slot-vmapped decode program for ``plan``: ``(params,
+    caches, tokens, t)`` -> (logits, caches), caches donated; RESIDENT
+    adds the health-mask input."""
+    if failover == RESIDENT:
+        names = list(model_stage_names(cfg))
+
+        def step(params, cache, tokens, t, mask):
+            routes = plan.resident_routes(mask, names)
+            model = build_model(cfg, routes=routes)
+            return model.decode_step(params, cache, tokens, t)
+
+        return jax.jit(jax.vmap(step, in_axes=(None, 0, 0, 0, None)),
+                       donate_argnums=(1,))
+    model = build_model(cfg, routes=plan)
+    return jax.jit(jax.vmap(model.decode_step, in_axes=(None, 0, 0, 0)),
+                   donate_argnums=(1,))
+
+
 class ServeEngine(_SlotPool):
     """Continuous-batching engine; all routing flows through RoutingPlan.
 
     Slot-pool state lives on the instance (``reset_pool`` / ``admit`` /
     ``decode_tick`` / ``drain``), so the same pool machinery serves both
     the single-device ``serve`` loop and the per-device workers of
-    ``FleetServeEngine``.
+    ``FleetServeEngine``.  ``device`` commits the weights and the KV pool
+    to one device, and every program of this pool then runs there; None
+    leaves them on the default device.
     """
 
     def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig, *,
                  dispatchers: Optional[Tuple[Dispatcher, Dispatcher]] = None,
                  template: Optional["ServeEngine"] = None,
-                 classifier=None):
+                 classifier=None, device=None):
         if scfg.failover not in (RECOMPILE, RESIDENT):
             raise ValueError(f"unknown failover mode {scfg.failover!r}; "
                              f"expected {RECOMPILE!r} or {RESIDENT!r}")
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.scfg = scfg
         self.classifier = classifier   # core.fault.FaultClassifier | None
         self.fault_state = FaultState()
@@ -305,7 +346,7 @@ class ServeEngine(_SlotPool):
             # per-device (jit caches are per-function-instance, so a
             # private _insert would recompile once per worker).
             self._shape_model = template._shape_model
-            self._cache0 = template._cache0
+            self._cache0 = self._placed(template._cache0)
             self._insert = template._insert
         else:
             # Route-free model instance, for cache/shape structure only.
@@ -313,7 +354,8 @@ class ServeEngine(_SlotPool):
             # Zero KV template, shared by every admission (prefill does
             # not donate its inputs, so one allocation serves the engine
             # lifetime).
-            self._cache0 = self._shape_model.init_cache(1, scfg.max_len)
+            self._cache0 = self._placed(
+                self._shape_model.init_cache(1, scfg.max_len))
             # Donating jitted slot insert: writing a prefilled lane into
             # the S-slot pool must not copy the whole pool per admission.
             self._insert = jax.jit(
@@ -325,13 +367,23 @@ class ServeEngine(_SlotPool):
         self.reset_pool()
 
     # --------------------------------------------------------- pool state
+    def placement(self) -> set:
+        """Every device holding this pool's weights or KV cache."""
+        return {d for x in jax.tree_util.tree_leaves((self.params,
+                                                      self._caches))
+                for d in x.devices()}
+
+    def _placed(self, tree):
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
+
     def reset_pool(self):
         """Fresh slot pool: no admitted sequences, full capacity."""
         S = self.scfg.max_slots
         self._caches = jax.tree_util.tree_map(
             lambda a: jnp.stack([a] * S), self._cache0)
-        self._toks = jnp.zeros((S, 1, 1), jnp.int32)
-        self._tvec = jnp.zeros((S,), jnp.int32)
+        self._toks = self._placed(jnp.zeros((S, 1, 1), jnp.int32))
+        self._tvec = self._placed(jnp.zeros((S,), jnp.int32))
         self._init_pool()
 
     # ------------------------------------------------------------- plans
@@ -390,42 +442,21 @@ class ServeEngine(_SlotPool):
 
     # ------------------------------------------------------------ builds
     def _build_prefill(self, plan: RoutingPlan):
-        if self.scfg.failover == RESIDENT:
-            # Admissions after a fault must not stall in-flight decodes on
-            # a recompile either: prefill is resident too (one executable
-            # per prompt length, rerouted by the same health mask).
-            names = list(self.stage_names)
-            cfg = self.cfg
-
-            def prefill(params, batch, mask):
-                routes = plan.resident_routes(mask, names)
-                return build_model(cfg, routes=routes).prefill(params, batch)
-
-            return jax.jit(prefill)
-        model = build_model(self.cfg, routes=plan)
-        return jax.jit(model.prefill)
-
-    def _run_prefill(self, params, batch):
-        key = self._decode_key()
-        if self.scfg.failover == RESIDENT:
-            return self._prefill.get(key)(params, batch, self.health_mask())
-        return self._prefill.get(key)(params, batch)
+        return build_prefill(self.cfg, plan, self.scfg.failover)
 
     def _build_decode(self, plan: RoutingPlan):
+        return build_decode(self.cfg, plan, self.scfg.failover)
+
+    def prefill(self, prompt) -> Tuple[jax.Array, Any]:
+        """Prefill one prompt under the current plan: (last-token logits
+        (1, 1, V), one-slot KV cache).  The program admission runs."""
+        batch = {"tokens": jnp.asarray(prompt, jnp.int32)[None],
+                 "cache": self._cache0}
+        key = self._decode_key()
         if self.scfg.failover == RESIDENT:
-            names = list(self.stage_names)
-            cfg = self.cfg
-
-            def step(params, cache, tokens, t, mask):
-                routes = plan.resident_routes(mask, names)
-                model = build_model(cfg, routes=routes)
-                return model.decode_step(params, cache, tokens, t)
-
-            return jax.jit(jax.vmap(step, in_axes=(None, 0, 0, 0, None)),
-                           donate_argnums=(1,))
-        model = build_model(self.cfg, routes=plan)
-        return jax.jit(jax.vmap(model.decode_step, in_axes=(None, 0, 0, 0)),
-                       donate_argnums=(1,))
+            return self._prefill.get(key)(self.params, batch,
+                                          self.health_mask())
+        return self._prefill.get(key)(self.params, batch)
 
     # --------------------------------------------------------- admission
     def _validate(self, requests: Sequence[Request]):
@@ -438,10 +469,8 @@ class ServeEngine(_SlotPool):
         Returns the number of tokens emitted (always 1: the prefill
         argmax)."""
         i = next(idx for idx, sl in enumerate(self._slots) if sl is None)
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-        P = prompt.shape[1]
-        logits, cache = self._run_prefill(
-            self.params, {"tokens": prompt, "cache": self._cache0})
+        P = len(req.prompt)
+        logits, cache = self.prefill(req.prompt)
         first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)   # (1,)
         self._caches = self._insert(self._caches, cache, jnp.int32(i))
         self._toks = self._toks.at[i].set(first[:, None])
@@ -676,6 +705,13 @@ class FleetServeEngine:
     the pool has one — Fig. 8 — otherwise on whatever capacity survives).
     The per-device pools share one Dispatcher pair, so devices with equal
     RoutingPlans share compiled executables.
+
+    Placement: when the process's device list covers the fleet, logical
+    device ``d``'s weights and KV pool live on ``jax.devices()[d]`` (under
+    ``jax.distributed`` that is the global list, so a host's local pools
+    land on its own chips).  A fleet larger than the device list is
+    emulated on the default device — the single-process benches and tests
+    run that way.  ``pool_devices`` records what each pool got.
     """
 
     def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig,
@@ -714,13 +750,23 @@ class FleetServeEngine:
                                        n_spares=fcfg.n_spares)
         # Real slot pools for this host's device block, bookkeeping
         # shadows for everyone else's (single-host: everything is real).
+        devices = jax.devices()
+        placed = len(devices) >= fcfg.n_devices
         self.workers: List[_SlotPool] = []
+        self.pool_devices: List[Any] = []
         shared: Optional[Tuple[Dispatcher, Dispatcher]] = None
         template: Optional[ServeEngine] = None
         for d in range(fcfg.n_devices):
+            dev = None
             if self.topology is None or self.topology.is_local(d):
+                if placed:
+                    dev = devices[d]
+                    if dev.process_index != jax.process_index():
+                        raise ValueError(
+                            f"fleet device {d} is local to this host but "
+                            f"{dev} belongs to process {dev.process_index}")
                 w = ServeEngine(cfg, params, scfg, dispatchers=shared,
-                                template=template)
+                                template=template, device=dev)
                 if shared is None:
                     shared = (w._prefill, w._decode)
                 if template is None:
@@ -729,6 +775,7 @@ class FleetServeEngine:
                 w = _ShadowWorker(scfg)
             w.device_index = d
             self.workers.append(w)
+            self.pool_devices.append(dev)
         self._prefill, self._decode = shared if shared else (None, None)
         self.event_log: List[dict] = []
         self._sync_capacity()
@@ -1291,23 +1338,55 @@ def synthetic_workload(vocab_size: int, n_requests: int, rng, *,
                arrival_every=arrival_every, per_arrival=per_arrival)
 
 
+@functools.lru_cache(maxsize=8)
+def _reference_programs(cfg: ModelConfig, routes):
+    model = build_model(cfg, routes=routes)
+    return model, jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
 def reference_decode(cfg: ModelConfig, params, prompt, n_new: int, *,
                      max_len: int, routes: Optional[RoutingPlan] = None
                      ) -> np.ndarray:
     """Single-request greedy decode straight on the model — no slots, no
     vmap, no engine.  The per-request oracle the batching engine must match
-    bit-for-bit (used by tests and serve_bench)."""
-    model = build_model(cfg, routes=routes)
+    bit-for-bit (used by tests and serve_bench).  The jitted programs are
+    kept per (config, routes), so checking many requests compiles once per
+    prompt length."""
+    return _reference_run(cfg, params, prompt, n_new, max_len=max_len,
+                          routes=routes)[0]
+
+
+def reference_margins(cfg: ModelConfig, params, prompt, tokens, *,
+                      max_len: int, routes: Optional[RoutingPlan] = None
+                      ) -> np.ndarray:
+    """The reference decode forced along ``tokens`` (a completion): for
+    each token, how far the reference's top logit lies above the logit of
+    the token taken — all zero iff every token is the reference's greedy
+    choice.  Where the slot-batched and single-request programs round
+    differently (bf16 on a TPU), a correct engine leaves the reference's
+    greedy path only at near-ties, so its margins stay within rounding."""
+    return _reference_run(cfg, params, prompt, len(tokens), max_len=max_len,
+                          routes=routes, forced=tokens)[1]
+
+
+def _reference_run(cfg, params, prompt, n_new, *, max_len, routes,
+                   forced=None):
+    """(tokens, margins): greedy single-request decode, or the decode
+    forced along ``forced``, with each step's top-logit margin."""
+    model, prefill, step_fn = _reference_programs(cfg, routes)
     prompt = jnp.asarray(prompt, jnp.int32)[None]
     P = prompt.shape[1]
     cache = model.init_cache(1, max_len)
-    logits, state = jax.jit(model.prefill)(
-        params, {"tokens": prompt, "cache": cache})
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-    out = [int(tok[0, 0])]
-    step_fn = jax.jit(model.decode_step)
-    for i in range(n_new - 1):
-        logits, state = step_fn(params, state, tok, jnp.int32(P + i))
-        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-        out.append(int(tok[0, 0]))
-    return np.asarray(out, np.int32)
+    logits, state = prefill(params, {"tokens": prompt, "cache": cache})
+    toks, margins = [], []
+    for i in range(n_new):
+        if i:
+            logits, state = step_fn(params, state,
+                                    jnp.full((1, 1), toks[-1], jnp.int32),
+                                    jnp.int32(P + i - 1))
+        row = np.asarray(logits[0, -1], np.float32)
+        best = int(np.argmax(row))
+        tok = best if forced is None else int(forced[i])
+        toks.append(tok)
+        margins.append(row[best] - row[tok])
+    return np.asarray(toks, np.int32), np.asarray(margins, np.float32)

@@ -61,6 +61,10 @@ class OpSpec:
     # (args, kw) with the lane-axis operands sliced to the surviving lanes;
     # the kernel then derives its output width from the sliced operand.
     lane_slicer: Optional[Callable[..., Any]] = None
+    # Canary inputs: (*standard-normal draws) -> (*args in the op's
+    # operating domain), for ops whose math is only stable there (the SSD
+    # scan needs dt > 0 and A < 0, as the model feeds it).
+    canary_domain: Optional[Callable[..., Any]] = None
 
     def lower(self, target) -> Callable[..., Any]:
         if hasattr(target, "target_for"):   # RoutingPlan: my stage's entry
@@ -106,12 +110,14 @@ REGISTRY = Registry()
 
 
 def defop(name: str, *, ref, kernel=None, interpret=None, valid=None,
-          tol: float = 2e-2, flops=None, lane_slicer=None) -> OpSpec:
+          tol: float = 2e-2, flops=None, lane_slicer=None,
+          canary_domain=None) -> OpSpec:
     """Declare an op once; both lowerings become available framework-wide."""
     return REGISTRY.register(OpSpec(name=name, ref=ref, kernel=kernel,
                                     interpret=interpret, valid=valid,
                                     tol=tol, flops=flops,
-                                    lane_slicer=lane_slicer))
+                                    lane_slicer=lane_slicer,
+                                    canary_domain=canary_domain))
 
 
 def finite_valid(out) -> jax.Array:

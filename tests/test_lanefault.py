@@ -308,7 +308,7 @@ def test_injector_corrupts_nonzero_input(kind):
 # --------------------------------------------------- narrowed fail-opens
 def test_canary_expected_errors_flag_fault_and_log(caplog):
     def boom(x):
-        raise ValueError("datapath shape breakage")
+        raise FloatingPointError("invalid value (nan) in the datapath")
     stage = Stage(name="s", hw=boom, sw=lambda x: x,
                   ports=(jax.ShapeDtypeStruct((4,), jnp.float32),),
                   tol=0.0)
@@ -328,6 +328,25 @@ def test_canary_unexpected_errors_propagate():
     with pytest.raises(RuntimeError, match="genuine bug"):
         chk.check_stage(stage)
     assert RuntimeError not in EXPECTED_STAGE_ERRORS
+
+
+def test_canary_lowering_refusal_raises_instead_of_quarantining():
+    """A kernel the compiler refuses (Mosaic raises ValueError) is a
+    program error, not a fault: the canary re-raises, nothing is marked,
+    and the stage is never quietly rerouted onto its oracle."""
+    def refused(x):
+        raise ValueError("The Pallas TPU lowering currently requires that "
+                         "the last two dimensions of your block shape are "
+                         "divisible by 8 and 128 respectively")
+    stage = Stage(name="s", hw=refused, sw=lambda x: x,
+                  ports=(jax.ShapeDtypeStruct((4,), jnp.float32),),
+                  tol=1e-3)
+    chk = CanaryChecker([stage], localize=True)
+    state = FaultState()
+    with pytest.raises(ValueError, match="block shape"):
+        chk.sweep(state, step=1)
+    assert state.log == [] and not state.is_faulty("s")
+    assert ValueError not in EXPECTED_STAGE_ERRORS
 
 
 def test_sharding_constrain_narrow_except(monkeypatch):

@@ -9,7 +9,8 @@ import pytest
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import (RECOMPILE, RESIDENT, Request, ServeConfig,
-                         ServeEngine, reference_decode, synthetic_workload)
+                         ServeEngine, reference_decode, reference_margins,
+                         synthetic_workload)
 from repro.viscosity import INTERPRET, SW
 
 KEY = jax.random.PRNGKey(0)
@@ -261,3 +262,87 @@ def test_request_validation():
                                          r"'arrival_time'"):
         eng.serve([Request(rid=5, prompt=np.zeros(4, np.int32),
                            max_new_tokens=2, arrival_time=-0.5)])
+
+
+def test_reference_margins_zero_on_greedy_path_positive_off_it():
+    """Forced along reference_decode's own tokens every margin is zero;
+    forcing a different token at one step shows its logit gap there."""
+    cfg, params = _setup()
+    prompt = np.arange(1, 8, dtype=np.int32)
+    ref = reference_decode(cfg, params, prompt, 6, max_len=32)
+    np.testing.assert_array_equal(
+        reference_margins(cfg, params, prompt, ref, max_len=32),
+        np.zeros(6, np.float32))
+    off = ref.copy()
+    off[2] = (ref[2] + 1) % cfg.vocab_size
+    m = reference_margins(cfg, params, prompt, off, max_len=32)
+    assert m[2] > 0 and not m[:2].any()
+
+# ------------------------------------------------------- device placement
+FLEET_PLACEMENT_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import json
+import jax
+import numpy as np
+from repro.configs import get_config
+from repro.models import build_model
+from repro.serve import (FleetConfig, FleetServeEngine, Request,
+                         ServeConfig, ServeEngine)
+
+cfg = get_config("qwen1.5-4b").reduced()
+params = build_model(cfg).init(jax.random.PRNGKey(0))
+scfg = ServeConfig(max_len=48, max_slots=2)
+fleet = FleetServeEngine(cfg, params, scfg,
+                         FleetConfig(n_devices=4, n_spares=1))
+rng = np.random.default_rng(0)
+# every serving slot is full when device 0 fails: its work needs the spare
+reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 5 + i),
+                max_new_tokens=8, arrival=0) for i in range(6)]
+done, stats = fleet.serve(reqs, events={3: [("device", 0)]})
+ref, _ = ServeEngine(cfg, params, scfg).serve(reqs)
+print(json.dumps({
+    "pool_devices": [d.id for d in fleet.pool_devices],
+    "placement": [sorted(d.id for d in w.placement())
+                  for w in fleet.workers],
+    "per_device_tokens": stats["per_device_tokens"],
+    "quarantined": stats["quarantined"],
+    "same_tokens": all(np.array_equal(done[r.rid].tokens,
+                                      ref[r.rid].tokens) for r in reqs)}))
+"""
+
+
+def test_fleet_pools_sit_on_distinct_devices():
+    """With as many devices as fleet slots, every pool's weights and KV
+    cache are committed to its own device; a device fault migrates work
+    to the spare's device, and tokens equal a one-pool engine's."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    res = subprocess.run([sys.executable, "-c", FLEET_PLACEMENT_SCRIPT],
+                         env=dict(os.environ, PYTHONPATH=src),
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["pool_devices"] == [0, 1, 2, 3]
+    assert out["placement"] == [[0], [1], [2], [3]]
+    assert out["quarantined"] == [0]
+    assert out["per_device_tokens"][3] > 0
+    assert out["same_tokens"]
+
+
+def test_fleet_larger_than_device_list_is_emulated():
+    """A fleet with more slots than the process has devices runs every
+    pool on the default device (the single-process benches and tests)."""
+    from repro.serve import FleetConfig, FleetServeEngine
+
+    cfg, params = _setup()
+    fleet = FleetServeEngine(cfg, params, ServeConfig(max_len=32,
+                                                      max_slots=1),
+                             FleetConfig(n_devices=len(jax.devices()) + 1))
+    assert fleet.pool_devices == [None] * fleet.fcfg.n_devices
+    assert all(w.placement() == {jax.devices()[0]} for w in fleet.workers)
