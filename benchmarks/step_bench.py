@@ -15,6 +15,7 @@ from repro.data import DataConfig, SyntheticLM
 from repro.serve import ServeConfig, ServeEngine
 from repro.train import TrainConfig, TrainRunner
 from repro.models import build_model
+from repro.obs import trace as obs_trace
 
 
 def run(seed: int = 0):
@@ -70,9 +71,10 @@ def run(seed: int = 0):
                                                  hw_route=INTERPRET))
     prompts = jax.random.randint(jax.random.PRNGKey(seed + 1), (4, 32), 0,
                                  cfg.vocab_size).astype(jnp.int32)
-    toks, stats = eng.generate(prompts, 24,
-                               fault_at_step=(12, "flash_attention"))
-    st = stats["step_times"]
+    t0 = time.perf_counter()
+    eng.generate(prompts, 24, fault_at_step=(12, "flash_attention"))
+    st = [s.end - s.start for s in obs_trace.recent_spans(t0)[0]
+          if s.name == "serve.tick"]
     rows.append(("decode_step_healthy", float(np.median(st[:12]) * 1e6),
                  "b=4"))
     rows.append(("decode_failover_spike", float(st[12] * 1e6),

@@ -132,4 +132,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

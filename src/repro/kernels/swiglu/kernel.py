@@ -95,4 +95,5 @@ def swiglu_pallas(x, w1, w3, w2, *, act: str = "silu", bm: int = 128,
         out_shape=jax.ShapeDtypeStruct((M, Do), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, Do), jnp.float32)],
         interpret=interpret,
+        name="swiglu",
     )(x, w1, w3, w2)

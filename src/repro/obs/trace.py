@@ -1,32 +1,45 @@
-"""Logical-clock trace spans.
+"""Trace spans: logical-clock events and timed host spans.
 
-A trace is an ordered log of :class:`TraceEvent` records keyed by the
-same ``(step, origin, seq)`` logical clock the FleetEvent log and
-``FaultState`` stamps already use: ``step`` is the engine step the
-event belongs to, ``origin`` the emitting host, ``seq`` a per-origin
-monotone counter.  Merging traces from different hosts is the same
-sorted-dedup union the event log property-tests — so the merged,
+**Logical clock.**  A trace is an ordered log of :class:`TraceEvent`
+records keyed by the same ``(step, origin, seq)`` logical clock the
+FleetEvent log and ``FaultState`` stamps already use: ``step`` is the
+engine step the event belongs to, ``origin`` the emitting host, ``seq`` a
+per-origin monotone counter.  Merging traces from different hosts is the
+same sorted-dedup union the event log property-tests — so the merged,
 serialized trace is **byte-identical regardless of arrival
-interleaving** (:func:`merge` + :func:`to_jsonl`).
+interleaving** (:func:`merge` + :func:`to_jsonl`).  Events reach the
+installed :class:`Tracer` through module-level :func:`emit`: the SLO
+front end (``serve.frontend``) opens a ``req:<rid>`` span when it hands a
+request to the engine and closes it on completion or expiry, and records
+sheds; the fault classifier annotates probation episodes
+(``core.fault``); the fleet engine annotates fault events
+(``fleet:<kind>``).  The engine's session path emits no logical
+events.  :func:`spans_of` pairs ``span_start`` / ``span_end`` events by
+name; detect→recover pairs are how the MTTR histogram in
+``obs.metrics`` is derived.
 
-Span lifecycle (per request)::
-
-    admit                submit              ...ticks...      complete
-    span_start ──────────▶ annot ──────────────▶ annot ──────▶ span_end
-    (frontend release)    (engine slot)        (decode_tick)  (poll)
-
-plus out-of-band annotations for faults, probation episodes and
-ladder-rung transitions.  :func:`spans_of` pairs ``span_start`` /
-``span_end`` events by name; detect→recover pairs are how the MTTR
-histogram in ``obs.metrics`` is derived.
+**Timed spans.**  :class:`span` times a block of host code with
+``time.perf_counter``, the serving code's host clock, and keeps the
+record in a ring of the last :data:`SPAN_RING` spans owned by this module
+(always on; :func:`recent_spans` reads it).  Each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so under an active
+profiler trace the program's spans lie on the profiler's clock beside the
+device ops.  The serving engine's spans (``serve.step``, ``serve.admit``,
+``serve.tick`` and their children) are named in ``serve.engine``.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import json
+import math
+import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import (Any, Dict, Iterable, Iterator, List, Optional,
-                    Sequence, Tuple)
+from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple,
+                    Optional, Sequence, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 SPAN_START = "span_start"
 SPAN_END = "span_end"
@@ -203,3 +216,70 @@ def use(tracer: Tracer) -> Iterator[Tracer]:
 def emit(step: int, kind: str = ANNOT, name: str = "", **attrs) -> None:
     if _tracer_stack:
         _tracer_stack[-1].emit(step, kind, name, **attrs)
+
+
+# ---------------------------------------------------------- timed spans
+SPAN_RING = 65536        # timed spans kept; older ones are let go
+
+
+class TimedSpan(NamedTuple):
+    """One closed timed span: ``start``/``end`` in ``time.perf_counter``
+    seconds; ``parent`` is the ``id`` of the span it nests in (-1 at top
+    level)."""
+    id: int
+    name: str
+    start: float
+    end: float
+    parent: int
+    attrs: Dict[str, Any]
+
+
+_ring: collections.deque = collections.deque(maxlen=SPAN_RING)
+_open: List["span"] = []
+_ids = itertools.count()
+_let_go_end = -math.inf      # end of the newest span the ring let go
+
+
+class span:
+    """``with span(name, **attrs):`` times the block into the ring and,
+    under an active profiler trace, marks it there as a
+    ``TraceAnnotation`` of the same name carrying ``attrs``.  ``set``
+    adds attributes known only inside the block."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "start", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "span":
+        self.id = next(_ids)
+        self.parent = _open[-1].id if _open else -1
+        self._ann = TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        _open.append(self)
+        self.start = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        _open.pop()
+        self._ann.__exit__(*exc)
+        if len(_ring) == SPAN_RING:
+            global _let_go_end
+            _let_go_end = _ring[0].end
+        _ring.append(TimedSpan(self.id, self.name, self.start, end,
+                               self.parent, self.attrs))
+
+
+def recent_spans(t0: float, t1: float = math.inf
+                 ) -> Tuple[Tuple[TimedSpan, ...], bool]:
+    """The ring's spans that overlap ``[t0, t1)`` (``perf_counter``
+    seconds) in the order they closed, and whether the ring still holds
+    every span that ended after ``t0``."""
+    return (tuple(s for s in _ring if s.end > t0 and s.start < t1),
+            _let_go_end <= t0)

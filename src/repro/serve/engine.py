@@ -49,6 +49,15 @@ in-flight work on a spare owned by host B — the collective drain/re-admit
 is just the shared queue, no request ever dropped.  ``merge_completions``
 resolves each host's placeholder completions against the owning host's
 real tokens at the end.
+
+Timed host spans (``obs.trace.span``) mark the engine's boundaries:
+``serve.step`` (``EngineSession.step``), ``serve.admit`` with children
+``.prefill`` (mask and prefill dispatch), ``.insert`` (slot insert, token
+and position updates) and ``.wait`` (the first-token fetch), and
+``serve.tick`` with children ``.dispatch`` (mask, decode and argmax
+dispatch), ``.wait`` (``block_until_ready``), ``.fetch`` (position
+update, device-to-host token copy) and ``.slots`` (per-slot bookkeeping
+and eviction).
 """
 from __future__ import annotations
 
@@ -470,18 +479,24 @@ class ServeEngine(_SlotPool):
         argmax)."""
         i = next(idx for idx, sl in enumerate(self._slots) if sl is None)
         P = len(req.prompt)
-        logits, cache = self.prefill(req.prompt)
-        first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)   # (1,)
-        self._caches = self._insert(self._caches, cache, jnp.int32(i))
-        self._toks = self._toks.at[i].set(first[:, None])
-        self._tvec = self._tvec.at[i].set(P)
-        self._slots[i] = _Slot(rid=req.rid, prompt_len=len(req.prompt),
-                               arrival=req.arrival,
-                               remaining=req.max_new_tokens - 1,
-                               out=[int(first[0])], admitted_step=step,
-                               eligible_wall=eligible_wall, req=req)
-        if self._slots[i].remaining == 0:         # single-token request
-            self._finish(i, step, completions)
+        with obs_trace.span("serve.admit", rid=req.rid, prompt_len=P):
+            with obs_trace.span("serve.admit.prefill"):
+                logits, cache = self.prefill(req.prompt)
+                first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)  # (1,)
+            with obs_trace.span("serve.admit.insert"):
+                self._caches = self._insert(self._caches, cache,
+                                            jnp.int32(i))
+                self._toks = self._toks.at[i].set(first[:, None])
+                self._tvec = self._tvec.at[i].set(P)
+            with obs_trace.span("serve.admit.wait"):
+                tok = int(first[0])
+            self._slots[i] = _Slot(rid=req.rid, prompt_len=P,
+                                   arrival=req.arrival,
+                                   remaining=req.max_new_tokens - 1,
+                                   out=[tok], admitted_step=step,
+                                   eligible_wall=eligible_wall, req=req)
+            if self._slots[i].remaining == 0:     # single-token request
+                self._finish(i, step, completions)
         return 1
 
     # ------------------------------------------------------------- ticks
@@ -493,31 +508,37 @@ class ServeEngine(_SlotPool):
         active = self.active_slots()
         if not active:
             return {"active": 0, "dt": 0.0, "key": None, "tokens": 0}
-        key = self._decode_key()
-        fn = self._decode.get(key)
-        t0 = time.perf_counter()
-        if self.scfg.failover == RESIDENT:
-            logits, self._caches = fn(self.params, self._caches, self._toks,
-                                      self._tvec, self.health_mask())
-        else:
-            logits, self._caches = fn(self.params, self._caches, self._toks,
-                                      self._tvec)
-        nxt = jnp.argmax(logits[:, 0, -1], -1).astype(jnp.int32)      # (S,)
-        nxt.block_until_ready()
-        dt = time.perf_counter() - t0
-        metrics.observe("serve_decode_tick_seconds", dt)
-        self._toks = nxt[:, None, None]
-        S = self.scfg.max_slots
-        active_mask = np.zeros((S,), np.int32)
-        active_mask[active] = 1
-        self._tvec = self._tvec + jnp.asarray(active_mask)
-        nxt_np = np.asarray(nxt)
-        for i in active:
-            sl = self._slots[i]
-            sl.out.append(int(nxt_np[i]))
-            sl.remaining -= 1
-            if sl.remaining == 0:                 # evict finished
-                self._finish(i, step, completions)
+        with obs_trace.span("serve.tick", step=step, active=len(active)):
+            key = self._decode_key()
+            fn = self._decode.get(key)
+            t0 = time.perf_counter()
+            with obs_trace.span("serve.tick.dispatch"):
+                if self.scfg.failover == RESIDENT:
+                    logits, self._caches = fn(
+                        self.params, self._caches, self._toks, self._tvec,
+                        self.health_mask())
+                else:
+                    logits, self._caches = fn(
+                        self.params, self._caches, self._toks, self._tvec)
+                nxt = jnp.argmax(logits[:, 0, -1], -1).astype(jnp.int32)
+            with obs_trace.span("serve.tick.wait"):
+                nxt.block_until_ready()
+            dt = time.perf_counter() - t0
+            metrics.observe("serve_decode_tick_seconds", dt)
+            with obs_trace.span("serve.tick.fetch"):
+                self._toks = nxt[:, None, None]
+                S = self.scfg.max_slots
+                active_mask = np.zeros((S,), np.int32)
+                active_mask[active] = 1
+                self._tvec = self._tvec + jnp.asarray(active_mask)
+                nxt_np = np.asarray(nxt)
+            with obs_trace.span("serve.tick.slots"):
+                for i in active:
+                    sl = self._slots[i]
+                    sl.out.append(int(nxt_np[i]))
+                    sl.remaining -= 1
+                    if sl.remaining == 0:         # evict finished
+                        self._finish(i, step, completions)
         return {"active": len(active), "dt": dt, "key": key,
                 "tokens": len(active)}
 
@@ -1067,8 +1088,7 @@ class EngineSession(ServeSession):
         engine.reset_pool()
         self._decode_keys: set = set()
         self._prefill0 = engine._prefill.compiles
-        self.stats = {"step_times": [], "occupancy": [],
-                      "admitted": 0, "steps": 0}
+        self.stats = {"occupancy": [], "admitted": 0, "steps": 0}
 
     def _occupancy(self) -> int:
         return self.engine.occupancy()
@@ -1089,20 +1109,23 @@ class EngineSession(ServeSession):
                              "events; use ServeEngine.inject_fault (or "
                              "serve's fault_at_step)")
         eng, step = self.engine, self.step_count
-        now = time.perf_counter()
-        self._mark_eligible(now)
-        # admission: arrived requests claim free slots (join)
-        while (eng.has_free_slot() and self._queue
-               and self._queue[0].arrival <= step):
-            req = self._queue.popleft()
-            eng.admit(req, step, self._eligible_wall.get(req.rid, now),
-                      self._completions)
-            self.stats["admitted"] += 1
-        tick = eng.decode_tick(step, self._completions)
+        with obs_trace.span("serve.step", step=step) as sp:
+            now = time.perf_counter()
+            self._mark_eligible(now)
+            # admission: arrived requests claim free slots (join)
+            a0 = self.stats["admitted"]
+            while (eng.has_free_slot() and self._queue
+                   and self._queue[0].arrival <= step):
+                req = self._queue.popleft()
+                eng.admit(req, step, self._eligible_wall.get(req.rid, now),
+                          self._completions)
+                self.stats["admitted"] += 1
+            tick = eng.decode_tick(step, self._completions)
+            sp.set(admitted=self.stats["admitted"] - a0,
+                   active=tick["active"])
         self.step_count += 1
         if tick["active"]:
             self._decode_keys.add(tick["key"])
-            self.stats["step_times"].append(tick["dt"])
             self.stats["occupancy"].append(tick["active"])
         return tick
 

@@ -476,6 +476,8 @@ class FleetTrainRunner:
         while step_i < steps:
             if step_i in host_loss:
                 self.inject_host_fault(host_loss.pop(step_i), step=step_i)
+                if self.ckpt:
+                    self.ckpt.wait()     # a save still in flight counts
                 if self.ckpt and self.ckpt.steps():
                     params, opt_state, step_i = self._restore_latest(
                         params, opt_state, step_i)

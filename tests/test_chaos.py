@@ -324,6 +324,29 @@ def test_fleet_train_ckpt_cadence_and_host_restore(setup, tmp_path):
     assert len(steps) > len(set(steps))
 
 
+def test_fleet_train_host_restore_waits_for_the_save_in_flight(
+        setup, tmp_path, monkeypatch):
+    """A host lost while the latest checkpoint is still being written
+    restores from it once it lands, instead of running on unrestored."""
+    from repro.checkpoint.manager import CheckpointManager
+    write = CheckpointManager._write
+
+    def slow_write(self, *args):
+        time.sleep(1.0)
+        return write(self, *args)
+
+    monkeypatch.setattr(CheckpointManager, "_write", slow_write)
+    cfg, _ = setup
+    topo = HostTopology(num_hosts=2, devices_per_host=2)
+    r = _train_runner(cfg, TrainConfig(steps=4, hw_route=SW, ckpt_every=2,
+                                       ckpt_dir=str(tmp_path)), topo=topo)
+    params, opt = r.init_state()
+    r.run(params, opt, steps=4, host_loss={3: 1})
+    kinds = [e["kind"] for e in r.fault_state.log]
+    assert "checkpoint_restored" in kinds
+    assert [h["step"] for h in r.history].count(2) == 2
+
+
 # ------------------------------------------------------ campaign smokes
 def test_serve_campaign_smoke_invariants_green(setup):
     """Invariants green at small sizing, and the run's telemetry

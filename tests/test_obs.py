@@ -1,6 +1,7 @@
 """Telemetry-layer tests: metrics registry determinism, the Prometheus
-golden, the cross-host span-merge byte-identity property, the logging
-facade render format, and the instrumentation-overhead guard.
+golden, the cross-host span-merge byte-identity property, the timed-span
+ring, the logging facade render format, and the instrumentation-overhead
+guard.
 
 The determinism tests pin the exact-reproduction contract the benches
 rely on: two runs that record the same observations serialize to
@@ -205,6 +206,109 @@ def test_tracer_seq_monotone_and_kinds_checked():
     assert sorted(evs) == [evs[2], evs[0], evs[1]]  # clock order
     with pytest.raises(ValueError):
         trace.TraceEvent(step=0, origin=0, seq=0, kind="bogus")
+
+
+# -------------------------------------------------------- timed spans
+def _dur(s):
+    return s.end - s.start
+
+
+def test_timed_spans_nest_and_link_parents():
+    t0 = time.perf_counter()
+    with trace.span("obs.outer") as outer:
+        with trace.span("obs.a"):
+            with trace.span("obs.a.leaf"):
+                pass
+        with trace.span("obs.b"):
+            pass
+    spans, whole = trace.recent_spans(t0)
+    assert whole
+    by = {s.name: s for s in spans}
+    assert [s.name for s in spans] == ["obs.a.leaf", "obs.a", "obs.b",
+                                       "obs.outer"]      # in closing order
+    assert by["obs.outer"].id == outer.id
+    assert by["obs.outer"].parent == -1
+    assert by["obs.a"].parent == by["obs.b"].parent == outer.id
+    assert by["obs.a.leaf"].parent == by["obs.a"].id
+    assert by["obs.outer"].start <= by["obs.a"].start <= by["obs.a"].end \
+        <= by["obs.b"].start <= by["obs.b"].end <= by["obs.outer"].end
+    assert _dur(by["obs.a"]) + _dur(by["obs.b"]) <= _dur(by["obs.outer"])
+    # the window selects by overlap
+    late, _ = trace.recent_spans(by["obs.b"].start)
+    assert {s.name for s in late} == {"obs.b", "obs.outer"}
+    assert trace.recent_spans(t0, t0)[0] == ()
+
+
+def test_timed_span_attrs_carried_and_set_inside():
+    t0 = time.perf_counter()
+    with trace.span("obs.req", rid=12, prompt_len=64) as sp:
+        sp.set(admitted=2)
+    (s,), _ = trace.recent_spans(t0)
+    assert s.attrs == {"rid": 12, "prompt_len": 64, "admitted": 2}
+
+
+def test_timed_span_closes_on_exception():
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError):
+        with trace.span("obs.outer"):
+            with trace.span("obs.raises"):
+                raise RuntimeError("boom")
+    spans, _ = trace.recent_spans(t0)
+    assert [s.name for s in spans] == ["obs.raises", "obs.outer"]
+    with trace.span("obs.after"):     # the open-span stack unwound
+        pass
+    assert trace.recent_spans(t0)[0][-1].parent == -1
+
+
+def test_span_ring_is_bounded_and_reports_its_reach():
+    t0 = time.perf_counter()
+    with trace.span("obs.first"):
+        pass
+    assert trace.recent_spans(t0)[1]
+    for _ in range(trace.SPAN_RING):
+        with trace.span("obs.fill"):
+            pass
+    spans, whole = trace.recent_spans(t0)
+    assert len(spans) == trace.SPAN_RING and not whole
+    assert "obs.first" not in {s.name for s in spans}
+    # from the oldest span still held, the ring is whole again
+    assert trace.recent_spans(spans[0].start)[1]
+    t1 = time.perf_counter()
+    with trace.span("obs.last"):
+        pass
+    last, whole = trace.recent_spans(t1)
+    assert whole and [s.name for s in last] == ["obs.last"]
+
+
+def test_timed_spans_reach_an_active_profiler_trace(tmp_path):
+    """Without a profiler trace the ring alone records; under one, each
+    span is also a host event of the same name carrying its attributes."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    t0 = time.perf_counter()
+    with trace.span("obs.untraced", rid=1):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("obs.traced", rid=3) as sp:
+            with trace.span("obs.traced.child"):
+                pass
+            sp.set(active=2)
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in trace.recent_spans(t0)[0]] == [
+        "obs.untraced", "obs.traced.child", "obs.traced"]
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {ev.name: dict(ev.stats)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("obs.")}
+    assert events == {"obs.traced": {"rid": 3, "active": 2},
+                      "obs.traced.child": {}}
 
 
 # ----------------------------------------------------- logging facade

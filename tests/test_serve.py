@@ -227,6 +227,60 @@ def test_serve_is_thin_wrapper_over_session():
         toks, np.stack([done_g[i].tokens for i in range(2)]))
 
 
+def test_session_steps_emit_the_timed_span_tree():
+    """Every engine step leaves a ``serve.step`` span; its admissions and
+    its decode tick nest inside it, each with its children in order, and
+    children take no longer than their parent."""
+    import collections
+    import time
+
+    from repro.obs import trace
+    cfg, params = _setup()
+    reqs = _workload(cfg, 6, np.random.default_rng(5), max_new=4)
+    eng = ServeEngine(cfg, params, ServeConfig(max_len=64, max_slots=3))
+    sess = eng.session()
+    for r in reqs:
+        sess.submit(r)
+    t0 = time.perf_counter()
+    active = []
+    while sess.pending():
+        active.append(sess.step()["active"])
+    sess.close()
+    spans, whole = trace.recent_spans(t0)
+    assert whole
+    kids = collections.defaultdict(list)
+    for s in sorted(spans, key=lambda s: s.start):
+        kids[s.parent].append(s)
+    children = {"serve.admit": ["serve.admit.prefill", "serve.admit.insert",
+                                "serve.admit.wait"],
+                "serve.tick": ["serve.tick.dispatch", "serve.tick.wait",
+                               "serve.tick.fetch", "serve.tick.slots"]}
+
+    def dur(s):
+        return s.end - s.start
+
+    steps = kids[-1]
+    assert [s.name for s in steps] == ["serve.step"] * len(active)
+    assert [s.attrs["step"] for s in steps] == list(range(len(active)))
+    admits = []
+    for s, a in zip(steps, active):
+        assert s.attrs["active"] == a
+        n = s.attrs["admitted"]
+        assert [c.name for c in kids[s.id]] == \
+            ["serve.admit"] * n + ["serve.tick"] * (a > 0)
+        for c in kids[s.id]:
+            assert [g.name for g in kids[c.id]] == children[c.name]
+            assert all(not kids[g.id] for g in kids[c.id])
+            assert sum(dur(g) for g in kids[c.id]) <= dur(c)
+        assert sum(dur(c) for c in kids[s.id]) <= dur(s)
+        if a:
+            assert kids[s.id][-1].attrs == {"step": s.attrs["step"],
+                                            "active": a}
+        admits += kids[s.id][:n]
+    assert sorted((c.attrs["rid"], c.attrs["prompt_len"]) for c in admits) \
+        == sorted((r.rid, len(r.prompt)) for r in reqs)
+
+
 def test_request_validation():
     cfg, params = _setup()
     eng = ServeEngine(cfg, params, ServeConfig(max_len=16, max_slots=2))
