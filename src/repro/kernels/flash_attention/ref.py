@@ -1,18 +1,22 @@
 """Pure-jnp oracles for attention (the Viscosity "software" lowering).
 
-Two implementations:
+Three implementations:
   * ``attention_naive`` — the simple masked-softmax oracle used as the
     ground-truth in tests (never used at scale);
   * ``attention_chunked`` — the memory-efficient online-softmax jnp version
     (lax.scan over KV chunks).  This is the production software fallback and
-    the XLA path lowered by the dry-run.
+    the XLA path lowered by the dry-run;
+  * ``attention_decode`` — ``attention_naive``'s mathematics for one query
+    token per row over a heads-major KV cache (the LM decode step).
 
-Both support: causal masking, sliding windows (``window > 0``), GQA
+All support: causal masking, sliding windows (``window > 0``), GQA
 (``Hkv`` divides ``H``), gemma-style logit softcapping, and explicit
 query/key positions (decode: ``q_pos`` is the absolute position of the
-query tokens; ``kv_len`` masks the unwritten tail of a preallocated cache).
+query tokens; ``kv_len`` masks the unwritten tail of a preallocated cache,
+``attention_decode`` masks it by key position -1).
 
-Layout: q (B, Sq, H, D); k, v (B, Skv, Hkv, D); output (B, Sq, H, D).
+Layout: q (B, Sq, H, D); k, v (B, Skv, Hkv, D); output (B, Sq, H, D);
+``attention_decode`` takes q (B, H, D) and k, v (B, Hkv, Skv, D).
 """
 from __future__ import annotations
 
@@ -93,6 +97,34 @@ def attention_naive(q, k, v, *, causal: bool = True, window: int = 0,
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(vf.dtype), vf,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+def attention_decode(q, k, v, k_positions, q_pos, *, window: int = 0,
+                     softcap: float = 0.0, scale: float = 0.0) -> jax.Array:
+    """One query token per row over a heads-major cache, without moving it.
+
+    q (B, H, D); k, v (B, Hkv, Skv, D); ``k_positions`` (B, Skv) absolute
+    key positions, -1 for slots not yet written; ``q_pos`` (B,) the query's
+    position.  GQA reads q as (B, Hkv, G, D), query head h = kv head
+    h // G, as ``_repeat_kv`` maps them.  Causal; returns (B, H, D) in
+    q.dtype, equal to ``attention_naive`` on the same operands.
+    """
+    B, H, D = q.shape
+    Hkv = k.shape[1]
+    sc = scale or (1.0 / D ** 0.5)
+    qf = (q.astype(jnp.float32) * sc).astype(q.dtype)
+    qg = qf.reshape(B, Hkv, H // Hkv, D)
+    scores = jnp.einsum("bhgd,bhkd->bhgk", qg, k,
+                        preferred_element_type=jnp.float32)
+    scores = _softcap(scores, softcap)
+    mask = _mask(q_pos.astype(jnp.int32).reshape(B, 1),
+                 k_positions.astype(jnp.int32), causal=True, window=window,
+                 kv_len=None, explicit_kpos=True)          # (B, 1, Skv)
+    scores = jnp.where(mask[:, :, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgk,bhkd->bhgd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, H, D).astype(q.dtype)
 
 
 def attention_chunked(q, k, v, *, causal: bool = True, window: int = 0,

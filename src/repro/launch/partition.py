@@ -9,7 +9,8 @@ Name-driven rules (we control every param name):
 Indivisible dims fall back to replication (recorded; a hillclimb target).
 
 Batch inputs shard over ("pod","data"); decode caches shard batch over
-("pod","data") and kv-heads over "model" when divisible.
+("pod","data") and, in the heads-major KV cache (..., B, Hkv, S, D),
+kv-heads over "model" when divisible, else the sequence.
 """
 from __future__ import annotations
 
@@ -167,14 +168,14 @@ def make_cache_pspec_fn(batch: int, mesh: Mesh, attn_axis="model"):
             spec[b_dim] = b_axes
         name = path.split("/")[-1]
         if name in ("k", "v") and nd >= 2 and b_dim is not None:
-            # (..., B, S, Hkv, D): shard kv-heads over model if divisible;
+            # (..., B, Hkv, S, D): shard kv-heads over model if divisible;
             # else shard the SEQ dim (flash-decode style partial softmax —
             # XLA inserts the max/sum combines). Without this, MHA caches
             # (e.g. qwen1.5 kv=20) replicate and overflow HBM at 32k x 128.
-            if _div(shape[-2], m):
-                spec[-2] = attn_axis
-            elif _div(shape[-3], m):
+            if _div(shape[-3], m):
                 spec[-3] = attn_axis
+            elif _div(shape[-2], m):
+                spec[-2] = attn_axis
         elif name == "pos" and nd >= 2 and b_dim is not None:
             if _div(shape[-1], m):
                 spec[-1] = attn_axis

@@ -3,10 +3,13 @@
 Stage-wrapped: the score/softmax/PV core routes through the Viscosity
 ``flash_attention`` op (HW = Pallas kernel, SW = chunked-jnp fallback).
 
-Cache layout (decode): k/v (B, Smax, Hkv, Dh) plus an explicit per-slot
-position array ``pos`` (B, Smax) initialized to -1.  Sliding-window archs
-allocate Smax = window and write slots round-robin (ring buffer); the
-position array makes masking uniform across both cases.
+Cache layout (decode): k/v heads-major (B, Hkv, Smax, Dh) plus an explicit
+per-slot position array ``pos`` (B, Smax) initialized to -1.  Sliding-window
+archs allocate Smax = window and write slots round-robin (ring buffer); the
+position array makes masking uniform across both cases.  Heads-major keeps
+(Smax, Dh) as the two minor dims: the TPU tiles them unpadded, so the
+per-slot cache write and the attention read share the pool's default
+layout and the decode program never re-lays the pool out.
 """
 from __future__ import annotations
 
@@ -124,34 +127,43 @@ def attn_full(p, x, cos, sin, *, n_heads, n_kv, head_dim, causal=True,
 
 def init_kv_cache(B, smax, n_kv, head_dim, dtype):
     return {
-        "k": jnp.zeros((B, smax, n_kv, head_dim), dtype),
-        "v": jnp.zeros((B, smax, n_kv, head_dim), dtype),
+        "k": jnp.zeros((B, n_kv, smax, head_dim), dtype),
+        "v": jnp.zeros((B, n_kv, smax, head_dim), dtype),
         "pos": jnp.full((B, smax), -1, jnp.int32),
     }
 
 
 def cache_write_prefill(cache, k, v):
-    """Write a prefill's k/v into the cache.
+    """Write a prefill's k/v (B, S, Hkv, Dh) into the heads-major cache.
 
     S <= Smax: plain write into slots [0, S).  S > Smax (ring buffer,
     windowed attention): keep the last Smax tokens, placed cyclically at
     slot = position % Smax so subsequent decode writes stay consistent.
+    Flash attention keeps its (B, S, H, D) operands; each layer's new rows
+    are transposed once, on their way into the cache.
     """
     B, S = k.shape[:2]
-    smax = cache["k"].shape[1]
+    smax = cache["k"].shape[2]
     c = dict(cache)
     if S <= smax:
-        c["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-        c["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+        def put(full, new):
+            # Through a one-layer (1, B, Hkv, S, Dh) view: the projection
+            # then has two readers of different shapes, and XLA's TPU
+            # compiler copies these rows per layer instead of re-laying
+            # the whole stacked weight out before the layer loop (for
+            # wv: 524 MB more prefill temp at qwen1.5-4b widths).
+            rows = jnp.swapaxes(new[None], 2, 3).astype(full.dtype)
+            return jax.lax.dynamic_update_slice(
+                full[None], rows, (0, 0, 0, 0, 0))[0]
+        c["k"] = put(cache["k"], k)
+        c["v"] = put(cache["v"], v)
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
         c["pos"] = jax.lax.dynamic_update_slice(cache["pos"], pos, (0, 0))
         return c
     p0 = S - smax                       # first kept absolute position
     idx = (jnp.arange(smax, dtype=jnp.int32) - p0) % smax  # keep-row per slot
-    c["k"] = k[:, p0:][:, idx].astype(cache["k"].dtype)
-    c["v"] = v[:, p0:][:, idx].astype(cache["v"].dtype)
+    c["k"] = jnp.swapaxes(k[:, p0:][:, idx], 1, 2).astype(cache["k"].dtype)
+    c["v"] = jnp.swapaxes(v[:, p0:][:, idx], 1, 2).astype(cache["v"].dtype)
     pos = jnp.broadcast_to((p0 + idx)[None], (B, smax))
     c["pos"] = pos
     return c
@@ -165,14 +177,14 @@ def attn_decode(p, x, cache, t, *, n_heads, n_kv, head_dim, window=0,
     Writes slot ``t % Smax`` (ring buffer when Smax == window), attends over
     the cache with explicit per-slot positions.
 
-    ``layer``: if given, ``cache`` leaves are LAYER-STACKED (L, B, S, ...)
-    and this layer's row is updated with a single in-place
+    ``layer``: if given, ``cache`` leaves are LAYER-STACKED (L, B, Hkv,
+    Smax, Dh) and this layer's row is updated with a single in-place
     dynamic-update-slice (the decode path unrolls layers so the donated
     stacked cache is never copied).
     """
     B = x.shape[0]
     stacked = layer is not None
-    smax = cache["k"].shape[2 if stacked else 1]
+    smax = cache["k"].shape[3 if stacked else 2]
     q, k, v = _project_qkv(p, x, n_heads, n_kv, head_dim)
     tvec = jnp.full((B, 1), t, jnp.int32)
     if mrope is not None:
@@ -186,21 +198,24 @@ def attn_decode(p, x, cache, t, *, n_heads, n_kv, head_dim, window=0,
         k = rope_mod.apply_rope(k, cos, sin)
     slot = jnp.mod(t, smax)
     c = dict(cache)
-    kw = k.astype(cache["k"].dtype)
-    vw = v.astype(cache["v"].dtype)
+    # the new token's (Hkv, Dh) row, heads-major: (B, Hkv, 1, Dh)
+    kw = jnp.swapaxes(k, 1, 2).astype(cache["k"].dtype)
+    vw = jnp.swapaxes(v, 1, 2).astype(cache["v"].dtype)
     if stacked:
         c["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], kw[None], (layer, 0, slot, 0, 0))
+            cache["k"], kw[None], (layer, 0, 0, slot, 0))
         c["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], vw[None], (layer, 0, slot, 0, 0))
+            cache["v"], vw[None], (layer, 0, 0, slot, 0))
         c["pos"] = jax.lax.dynamic_update_slice(
             cache["pos"], tvec[None], (layer, 0, slot))
         k_all = jax.lax.dynamic_slice_in_dim(c["k"], layer, 1, 0)[0]
         v_all = jax.lax.dynamic_slice_in_dim(c["v"], layer, 1, 0)[0]
         pos_all = jax.lax.dynamic_slice_in_dim(c["pos"], layer, 1, 0)[0]
     else:
-        c["k"] = jax.lax.dynamic_update_slice(cache["k"], kw, (0, slot, 0, 0))
-        c["v"] = jax.lax.dynamic_update_slice(cache["v"], vw, (0, slot, 0, 0))
+        c["k"] = jax.lax.dynamic_update_slice(cache["k"], kw,
+                                              (0, 0, slot, 0))
+        c["v"] = jax.lax.dynamic_update_slice(cache["v"], vw,
+                                              (0, 0, slot, 0))
         c["pos"] = jax.lax.dynamic_update_slice(cache["pos"], tvec, (0, slot))
         k_all, v_all, pos_all = c["k"], c["v"], c["pos"]
     # The LM decode step attends through the jnp oracle on every route:
@@ -208,10 +223,9 @@ def attn_decode(p, x, cache, t, *, n_heads, n_kv, head_dim, window=0,
     # 1.2).  Counted, so a run can report which lowering decode used.
     metrics.inc("attention_lowerings_traced_total", phase="decode",
                 lowering="jnp_naive")
-    o = attn_ref.attention_naive(
-        q, k_all, v_all, causal=True, window=window, softcap=softcap,
-        scale=scale, q_offset=jnp.full((B,), t, jnp.int32),
-        k_positions=pos_all)
+    o = attn_ref.attention_decode(
+        q[:, 0], k_all, v_all, pos_all, jnp.full((B,), t, jnp.int32),
+        window=window, softcap=softcap, scale=scale)
     out = jnp.einsum("bsh,hd->bsd", o.reshape(B, 1, -1),
                      p["wo"].astype(x.dtype))
     return out, c

@@ -170,6 +170,9 @@ class LMModel:
         Cache structure (uniform-attention & rwkv families):
           {"grp": tuple_j of stacked (G, ...) caches for pattern position j,
            "tail": tuple_j of single caches for the tail layers}
+        An attention cache holds heads-major k/v (B, Hkv, Smax, Dh) and
+        ``pos`` (B, Smax) (``attention.init_kv_cache``); stacked, (G, B,
+        Hkv, Smax, Dh), and a serving pool adds the slot axis in front.
         Per-position tuples let local/global layers carry different cache
         lengths (ring buffers vs full KV) through one scan.
         """

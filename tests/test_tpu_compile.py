@@ -14,6 +14,7 @@ for a described chip cannot be read back without one).
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,16 +120,27 @@ def test_canary_kernels_compile(sds, name):
 
 
 # ----------------------------------------------------- served programs
-@pytest.fixture(scope="module")
-def one_layer(sds):
-    """qwen1.5-4b at published widths, one layer, as the server holds it
-    (bf16 weights): the config, the all-HW plan, and placed shapes."""
+def _served(sds, num_layers):
+    """qwen1.5-4b at published widths, cut to ``num_layers``, as the server
+    holds it (bf16 weights): the config, the all-HW plan, placed shapes."""
     cfg = dataclasses.replace(served_config("qwen1.5-4b", full=True),
-                              num_layers=1)
+                              num_layers=num_layers)
     plan = RoutingPlan.for_stages(model_stage_names(cfg), target=HW)
     model = build_model(cfg)
     params = sds.tree(params_specs(model))
     return cfg, plan, model, params
+
+
+@pytest.fixture(scope="module")
+def one_layer(sds):
+    return _served(sds, 1)
+
+
+@pytest.fixture(scope="module")
+def two_layers(sds):
+    """Two layers: the smallest stack whose programs lay the KV pool and
+    the stacked weights out as the full model does (one layer does not)."""
+    return _served(sds, 2)
 
 
 def _mask(sds, cfg):
@@ -147,14 +159,51 @@ def test_prefill_program_compiles(sds, one_layer, failover, P):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("failover", [RECOMPILE, RESIDENT])
-def test_decode_program_compiles(sds, one_layer, failover):
-    """The slot-vmapped decode step the engine runs each tick."""
-    cfg, plan, model, params = one_layer
+def _decode_compiled(sds, served, failover):
+    """The slot-vmapped decode step the engine runs each tick, compiled,
+    and the shape of its KV pool's k and v."""
+    cfg, plan, model, params = served
     state, tok, t = decode_state_specs(cfg, model, 1, MAX_LEN)
     slots = jax.tree_util.tree_map(
         lambda s: sds((SLOTS,) + s.shape, s.dtype), (state, tok, t))
     args = (params,) + slots + ((_mask(sds, cfg),) if failover == RESIDENT
                                 else ())
     compiled = build_decode(cfg, plan, failover).lower(*args).compile()
+    pool = slots[0]["grp"][0]["k"].shape
+    return compiled, pool
+
+
+@pytest.mark.parametrize("failover", [RECOMPILE, RESIDENT])
+def test_decode_program_compiles(sds, one_layer, failover):
+    compiled, _ = _decode_compiled(sds, one_layer, failover)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("failover", [RECOMPILE, RESIDENT])
+def test_decode_updates_kv_pool_in_place(sds, two_layers, failover):
+    """The tick writes each slot's new k/v row into the donated pool and
+    reads the pool where it lies: no copy of the whole pool's k or v.
+    With (Smax, Hkv) as the minor dims the TPU lays the pool out
+    seq-major, the vmapped per-slot write runs row-major, and the program
+    copies the pool into that layout and back every tick."""
+    compiled, pool = _decode_compiled(sds, two_layers, failover)
+    shape = ",".join(str(d) for d in pool)
+    copies = re.findall(rf"= bf16\[{shape}\]\S* copy\(", compiled.as_text())
+    assert not copies, f"{len(copies)} copies of the KV pool {pool}"
+
+
+@pytest.mark.parametrize("failover", [RECOMPILE, RESIDENT])
+def test_prefill_keeps_stacked_wv_in_place(sds, two_layers, failover):
+    """Writing the heads-major cache in prefill leaves the stacked value
+    projection as it lies: no copy of ``wv`` re-laid out for the layer
+    loop (524 MB of prefill temp at 40 layers).  The benchmark cell's
+    longest prompt and cache length."""
+    cfg, plan, model, params = two_layers
+    cache = sds.tree(jax.eval_shape(lambda: model.init_cache(1, 1024)))
+    batch = {"tokens": sds((1, 640), jnp.int32), "cache": cache}
+    args = (params, batch) + ((_mask(sds, cfg),) if failover == RESIDENT
+                              else ())
+    txt = build_prefill(cfg, plan, failover).lower(*args).compile().as_text()
+    wv = [line for line in txt.splitlines()
+          if " copy(" in line and re.search(r"\[\\?'wv\\?'\]", line)]
+    assert not wv, wv[0][:200]
