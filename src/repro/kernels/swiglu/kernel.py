@@ -19,6 +19,7 @@ Tile knobs (bm, bf, bs) are swept by ``kernels/tuning`` — see
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,11 +68,13 @@ def _swiglu_kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_scr, *,
 
 def swiglu_pallas(x, w1, w3, w2, *, act: str = "silu", bm: int = 128,
                   bf: int = 512, bs: int = 128, interpret: bool = False,
-                  lane_fault=None):
+                  lane_fault=None, vmem_limit_bytes: Optional[int] = None):
     """x (M, D); w1/w3 (D, F); w2 (F, Do). M % bm == 0, F % bf == 0,
     bf % bs == 0 (after clamping each knob to its dim).  The output width
     is ``w2.shape[1]`` — normally D, narrower under DEGRADED_REDUCED
-    (reduced-width execution slices w2 to the surviving lanes)."""
+    (reduced-width execution slices w2 to the surviving lanes).
+    ``vmem_limit_bytes`` raises Mosaic's scoped-VMEM limit for tiles
+    whose blocks outgrow its default (None keeps the default)."""
     M, D = x.shape
     F = w1.shape[1]
     Do = w2.shape[1]
@@ -81,6 +84,9 @@ def swiglu_pallas(x, w1, w3, w2, *, act: str = "silu", bm: int = 128,
     assert M % bm == 0 and F % bf == 0, (M, bm, F, bf)
     assert bf % bs == 0, (bf, bs)
     grid = (M // bm, F // bf)
+    extra = {} if vmem_limit_bytes is None else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem_limit_bytes))}
     return pl.pallas_call(
         functools.partial(_swiglu_kernel, nf=F // bf, bs=bs, act=act,
                           lane_fault=lane_fault),
@@ -96,4 +102,5 @@ def swiglu_pallas(x, w1, w3, w2, *, act: str = "silu", bm: int = 128,
         scratch_shapes=[pltpu.VMEM((bm, Do), jnp.float32)],
         interpret=interpret,
         name="swiglu",
+        **extra,
     )(x, w1, w3, w2)

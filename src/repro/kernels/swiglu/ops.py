@@ -9,7 +9,11 @@ from repro import viscosity
 from repro.kernels import tuning
 from repro.kernels.swiglu import ref as _ref
 from repro.kernels.swiglu.kernel import swiglu_pallas
+from repro.kernels.tuning import space as _space
+from repro.obs import metrics
 from repro.viscosity import lanefault
+
+SPACE = _space.space_for("swiglu_mlp", "hw")
 
 
 def _round_up(n: int, m: int) -> int:
@@ -38,12 +42,30 @@ def _hw(x, w1, w3, w2, *, act: str = "silu", interpret: bool = False,
                                (128 if F % 128 == 0 else F))
     if bs is None:
         bs = cfg.get("bs") or (128 if min(bf, F) % 128 == 0 else bf)
+    # Scoped VMEM: tiles whose blocks pass the cap halve bf (while it
+    # stays a multiple of 128), then bm; a call whose blocks outgrow
+    # Mosaic's default asks for them plus headroom (space.py).
+    itemsize = jnp.dtype(x.dtype).itemsize
+
+    def need():       # of the rows as padded to whole row tiles
+        return SPACE.vmem({"bm": bm, "bf": bf}, (_round_up(M, bm), D, F),
+                          itemsize)
+
+    while need() > SPACE.vmem_budget and min(bf, F) % 256 == 0:
+        bf = min(bf, F) // 2
+    while need() > SPACE.vmem_budget and bm > 8:
+        bm = _round_up(bm // 2, 8)
+    bf = min(bf, F)
+    bs = min(bs, bf)
+    metrics.inc("swiglu_tiles_traced_total", d=D, f=F, bm=bm, bf=bf, bs=bs,
+                vmem_mib=f"{need() / _space.MIB:.2f}")
     Mp = _round_up(M, bm)
     if Mp != M:
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
     out = swiglu_pallas(x, w1, w3, w2, act=act, bm=bm, bf=bf, bs=bs,
                         interpret=interpret,
-                        lane_fault=lanefault.injection("swiglu_mlp"))
+                        lane_fault=lanefault.injection("swiglu_mlp"),
+                        vmem_limit_bytes=_space.vmem_limit(need()))
     return out[:M]
 
 

@@ -11,7 +11,11 @@ software paths' chunk sizes.  This module is the single declaration of
   * the **admissibility predicate**: MXU/sublane alignment, grid
     divisibility, and a VMEM budget — the same constraints the kernels
     assert at call time, checked *before* a config is ever measured so
-    the tuner can never persist a config the kernel would reject.
+    the tuner can never persist a config the kernel would reject;
+  * for swiglu, the scoped VMEM its tiles ask of Mosaic
+    (``_swiglu_hw_vmem``) and the ``vmem_limit_bytes`` a call then passes
+    (``vmem_limit``): the kernel wrapper and the admissibility check read
+    the same estimate against the same cap.
 
 Shapes are canonical tuples (the same ones ``tuning.lookup`` keys on):
 
@@ -32,11 +36,23 @@ HW = "hw"
 SW = "sw"
 
 # TPU geometry the admissibility rules encode (see guides/pallas_guide.md):
-# MXU is 128x128, the f32 min tile is (8, 128), VMEM is ~16 MB/core — we
-# budget half of it for the blocks a single grid step holds live.
+# MXU is 128x128, the f32 min tile is (8, 128).  Mosaic gives a kernel a
+# 16 MiB scoped-VMEM limit unless the call asks for more; flash attention
+# budgets half of that for the blocks a single grid step holds live.
 MXU_LANE = 128
 SUBLANE_F32 = 8
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+MIB = 1024 * 1024
+VMEM_BUDGET_BYTES = 8 * MIB
+
+# Scoped VMEM beyond the default (swiglu).  A v5e TensorCore has 128 MiB
+# of VMEM; a call may ask for at most half of it.  Mosaic's own scratch
+# (f32 operand casts, gate tiles) comes on top of the blocks the estimate
+# counts: 1.1-5.6 MiB at d_model 5120 on a described v5e, so a call asks
+# for its blocks plus a fixed headroom.
+VMEM_BYTES = 128 * MIB
+SCOPED_VMEM_DEFAULT = 16 * MIB
+SCOPED_VMEM_HEADROOM = 8 * MIB
+SCOPED_VMEM_CAP = VMEM_BYTES // 2
 
 
 @dataclass(frozen=True)
@@ -46,7 +62,8 @@ class KernelSpace:
     ``params`` maps knob name -> ordered candidate values (ascending, so
     the hillclimber's neighbor move is "one index up/down").
     ``admissible(cfg, shape)`` is the hard constraint; ``vmem(cfg, shape)``
-    estimates live block bytes for the VMEM budget (HW spaces only).
+    estimates live block bytes, which must not pass ``vmem_budget`` (HW
+    spaces only).
     """
 
     kernel: str
@@ -55,6 +72,7 @@ class KernelSpace:
     check: Optional[Callable[[Dict[str, int], Tuple[int, ...]], bool]] = None
     vmem: Optional[Callable[[Dict[str, int], Tuple[int, ...]], int]] = None
     defaults: Mapping[str, int] = field(default_factory=dict)
+    vmem_budget: int = VMEM_BUDGET_BYTES
 
     def admissible(self, cfg: Mapping[str, int],
                    shape: Tuple[int, ...]) -> bool:
@@ -66,7 +84,7 @@ class KernelSpace:
         if self.check is not None and not self.check(cfg, tuple(shape)):
             return False
         if self.vmem is not None and self.vmem(cfg, tuple(shape)) > \
-                VMEM_BUDGET_BYTES:
+                self.vmem_budget:
             return False
         return True
 
@@ -132,12 +150,26 @@ def _swiglu_hw_check(cfg, shape):
     return M % bm == 0 and F % bf == 0 and bf % min(bs, bf) == 0
 
 
-def _swiglu_hw_vmem(cfg, shape):
+def _swiglu_hw_vmem(cfg, shape, itemsize: int = 2) -> int:
+    """Scoped VMEM (bytes) the swiglu kernel's blocks hold: Mosaic double
+    buffers every block (x (bm, D), w1/w3 (D, bf), w2 (bf, D), out (bm,
+    D), in the operands' dtype, bf16 when served) and keeps the f32 (bm,
+    D) accumulator once.  At d_model 5120, d_ff 14336 it gives what a
+    described v5e's compiler reports for the blocks: 22.50M (bm 128, bf
+    256), 30.47M (bm 8, bf 512; reported 30.31M), 37.50M (bm 128, bf 512);
+    at bm 128, bf 128 it gives 15.00M, and Mosaic's own scratch takes the
+    call to 16.13M."""
     M, D, F = shape
     bm, bf = min(cfg["bm"], M), min(cfg["bf"], F)
-    bs = min(cfg["bs"], bf)
-    # x (bm, D), w1/w3 (D, bf), w2 (bf, D), acc (bm, D), gate tile (bm, bs)
-    return 4 * (bm * D + 3 * D * bf + bm * D + 2 * bm * bs)
+    return 2 * itemsize * (2 * bm * D + 3 * D * bf) + 4 * bm * D
+
+
+def vmem_limit(need: int) -> Optional[int]:
+    """The ``vmem_limit_bytes`` a call whose blocks hold ``need`` bytes
+    passes: None (Mosaic's default) where they fit it with the headroom,
+    else the blocks plus the headroom."""
+    ask = need + SCOPED_VMEM_HEADROOM
+    return None if ask <= SCOPED_VMEM_DEFAULT else ask
 
 
 # ------------------------------------------------------------ scan chunks
@@ -174,6 +206,7 @@ _declare(KernelSpace(
             "bs": (128, 256, 512)},
     check=_swiglu_hw_check, vmem=_swiglu_hw_vmem,
     defaults={"bm": 128, "bf": 512, "bs": 128},
+    vmem_budget=SCOPED_VMEM_CAP - SCOPED_VMEM_HEADROOM,
 ))
 _declare(KernelSpace(
     kernel="mamba2_ssd", kind=HW,

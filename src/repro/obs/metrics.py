@@ -97,6 +97,10 @@ SCHEMA: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "attention_lowerings_traced_total": (
         COUNTER, "attention call sites traced, by phase and lowering",
         ("phase", "lowering")),
+    "swiglu_tiles_traced_total": (
+        COUNTER, "swiglu calls traced, by width, the tiles chosen and the "
+                 "scoped VMEM their blocks hold (MiB)",
+        ("d", "f", "bm", "bf", "bs", "vmem_mib")),
     # fault / routing
     "fault_events_total": (
         COUNTER, "fault-log entries by kind", ("kind", "stage")),

@@ -508,7 +508,12 @@ class ServeEngine(_SlotPool):
         active = self.active_slots()
         if not active:
             return {"active": 0, "dt": 0.0, "key": None, "tokens": 0}
-        with obs_trace.span("serve.tick", step=step, active=len(active)):
+        # positions the tick attends: each slot's prompt and the tokens
+        # served so far, the newest of which the tick writes
+        kv_live = sum(min(self._slots[i].prompt_len + len(self._slots[i].out),
+                          self.scfg.max_len) for i in active)
+        with obs_trace.span("serve.tick", step=step, active=len(active),
+                            kv_live=kv_live):
             key = self._decode_key()
             fn = self._decode.get(key)
             t0 = time.perf_counter()

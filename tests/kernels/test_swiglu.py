@@ -57,3 +57,28 @@ def test_property_matches_oracle(M, D, F):
     out = swiglu(x, w1, w3, w2, route="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4,
                                rtol=1e-4)
+
+
+@pytest.mark.parametrize("M,D,F,tiles", [
+    # qwen1.5-4b: the tiles it always had, under Mosaic's default limit
+    (640, 2560, 6912, ("128", "128", "128", "7.50")),
+    (1, 2560, 6912, ("8", "128", "128", "3.98")),
+    # mistral-nemo-12b: bf 512 fits under the cap, with a raised limit
+    (4096, 5120, 14336, ("128", "512", "128", "37.50")),
+    (1, 5120, 14336, ("8", "512", "128", "30.47"))])
+def test_tiles_counted_when_traced(M, D, F, tiles):
+    """Each traced call counts its widths, the tiles chosen and the scoped
+    VMEM their blocks hold (bf16, as served), once a trace."""
+    import functools
+
+    import jax
+
+    from repro.obs import metrics
+    reg = metrics.Registry()
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16)
+            for s in ((M, D), (D, F), (D, F), (F, D))]
+    with metrics.use(reg):
+        jax.make_jaxpr(functools.partial(swiglu, route="hw"))(*args)
+    fam = reg.families["swiglu_tiles_traced_total"]
+    assert dict(fam.samples) == \
+        {(str(D), str(F)) + tiles: 1.0}

@@ -205,7 +205,7 @@ def test_swiglu_sweep_configs_are_admissible(mi, fi):
         bm, bf = min(cfg["bm"], mi), min(cfg["bf"], fi)
         assert mi % bm == 0 and fi % bf == 0   # grid divisibility
         assert bf % min(cfg["bs"], bf) == 0    # hidden sub-tile streams
-        assert space.vmem(cfg, shape) <= VMEM_BUDGET_BYTES
+        assert space.vmem(cfg, shape) <= space.vmem_budget
 
 
 @settings(max_examples=30, deadline=None)

@@ -230,7 +230,9 @@ def test_serve_is_thin_wrapper_over_session():
 def test_session_steps_emit_the_timed_span_tree():
     """Every engine step leaves a ``serve.step`` span; its admissions and
     its decode tick nest inside it, each with its children in order, and
-    children take no longer than their parent."""
+    children take no longer than their parent.  A tick's ``kv_live`` is
+    the positions its slots attend: each admitted request's prompt and
+    the tokens it has been served, the newest of which the tick writes."""
     import collections
     import time
 
@@ -262,7 +264,8 @@ def test_session_steps_emit_the_timed_span_tree():
     steps = kids[-1]
     assert [s.name for s in steps] == ["serve.step"] * len(active)
     assert [s.attrs["step"] for s in steps] == list(range(len(active)))
-    admits = []
+    admits, live = [], {}     # rid -> (prompt_len, tokens served)
+    budget = {r.rid: r.max_new_tokens for r in reqs}
     for s, a in zip(steps, active):
         assert s.attrs["active"] == a
         n = s.attrs["admitted"]
@@ -273,9 +276,15 @@ def test_session_steps_emit_the_timed_span_tree():
             assert all(not kids[g.id] for g in kids[c.id])
             assert sum(dur(g) for g in kids[c.id]) <= dur(c)
         assert sum(dur(c) for c in kids[s.id]) <= dur(s)
+        for c in kids[s.id][:n]:
+            if budget[c.attrs["rid"]] > 1:
+                live[c.attrs["rid"]] = (c.attrs["prompt_len"], 1)
         if a:
-            assert kids[s.id][-1].attrs == {"step": s.attrs["step"],
-                                            "active": a}
+            assert kids[s.id][-1].attrs == {
+                "step": s.attrs["step"], "active": a,
+                "kv_live": sum(p + k for p, k in live.values())}
+            live = {rid: (p, k + 1) for rid, (p, k) in live.items()
+                    if k + 1 < budget[rid]}
         admits += kids[s.id][:n]
     assert sorted((c.attrs["rid"], c.attrs["prompt_len"]) for c in admits) \
         == sorted((r.rid, len(r.prompt)) for r in reqs)
